@@ -32,6 +32,7 @@ artifacts, if present) are appended at the end.
 from __future__ import annotations
 
 import argparse
+import glob
 import inspect
 import json
 import os
@@ -40,6 +41,7 @@ import time
 
 def main(argv=None) -> None:
     from benchmarks.paper_tables import ALL_TABLES
+    from repro.launch import enable_compile_cache
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", choices=["quick", "default", "paper"],
@@ -86,6 +88,7 @@ def main(argv=None) -> None:
 
     if args.buckets is not None and args.buckets < 1:
         ap.error(f"--buckets must be >= 1, got {args.buckets}")
+    enable_compile_cache()
     os.makedirs(args.out, exist_ok=True)
     only = set(args.only.split(",")) if args.only else None
     if only:
@@ -145,18 +148,19 @@ def main(argv=None) -> None:
         print(f"serve_trace,_wall_s={time.perf_counter() - t0:.1f}",
               flush=True)
 
-    # roofline table from dry-run artifacts when available
-    try:
-        from benchmarks.roofline import fmt_table, table
+    # roofline table from dry-run artifacts, where a dry run wrote them
+    if not glob.glob(os.path.join(args.dryrun_dir, "*.json")):
+        print(f"# roofline skipped: no dry-run artifacts in "
+              f"{args.dryrun_dir}")
+        return
+    from benchmarks.roofline import fmt_table, table
 
-        rows = table(args.dryrun_dir, mesh="16x16")
-        if rows:
-            print("\n# Roofline (16x16, from dry-run artifacts)")
-            print(fmt_table(rows))
-            with open(os.path.join(args.out, "roofline.json"), "w") as f:
-                json.dump(rows, f, indent=1)
-    except Exception as e:  # dry-run not yet produced
-        print(f"# roofline skipped: {e}")
+    rows = table(args.dryrun_dir, mesh="16x16")
+    if rows:
+        print("\n# Roofline (16x16, from dry-run artifacts)")
+        print(fmt_table(rows))
+        with open(os.path.join(args.out, "roofline.json"), "w") as f:
+            json.dump(rows, f, indent=1)
 
 
 if __name__ == "__main__":

@@ -66,9 +66,9 @@ untouched):
   * ``precision='mixed'`` (default) / ``'f64'`` — mixed precision runs
     the f32 iterate with an f64 KKT certificate and a final f64 polish
     pass (kept per lane only where it tightens the gap); 'f64' runs the
-    whole iterate in f64.  Both trace under a scoped ``enable_x64``
-    (the compiled placement stepper's discipline), so the process-wide
-    precision default is untouched.
+    whole iterate in f64.  Both trace under a scoped
+    ``jax.enable_x64(True)`` (the compiled placement stepper's
+    discipline), so the process-wide precision default is untouched.
   * ``solve_lp_sweep(..., pipeline=True)`` — the warm-started sweep
     chain compiled into ONE ``lax.scan`` over groups (one device
     dispatch for the whole chain), optionally sharded over the batch
@@ -345,6 +345,10 @@ def _make_operators(w_all, start, end, Tp: int, operator: str):
     w_flat = w_all.reshape(B, n, m * D)
 
     if operator == "dense":
+        # full f32 (or f64) products: the TPU's default single bf16
+        # pass would leave fwd/adj inexact adjoints at ~1e-3, the
+        # order of the stopping tolerance
+        hi = jax.lax.Precision.HIGHEST
         t_ids = jnp.arange(Tp, dtype=jnp.int32)
         act_nt = ((start[:, :, None] <= t_ids[None, None, :])
                   & (t_ids[None, None, :] <= end[:, :, None])
@@ -353,10 +357,12 @@ def _make_operators(w_all, start, end, Tp: int, operator: str):
 
         def fwd_all(xv):
             xw = (xv[..., None] * w_all).reshape(B, n, m * D)
-            return jnp.matmul(act_tn, xw).reshape(B, Tp, m, D)
+            return jnp.matmul(act_tn, xw, precision=hi).reshape(
+                B, Tp, m, D)
 
         def adj_all(yv):
-            z = jnp.matmul(act_nt, yv.reshape(B, Tp, m * D))
+            z = jnp.matmul(act_nt, yv.reshape(B, Tp, m * D),
+                           precision=hi)
             return jnp.sum(z.reshape(B, n, m, D) * w_all, axis=3)
         return fwd_all, adj_all
 
@@ -562,8 +568,8 @@ def _tol_core(w_all, start, end, feas, cost, step_scale, tol,
     on the way out — callers only ever see original coordinates);
     ``precision`` picks the iterate dtype (``'mixed'`` = f32 iterate,
     f64 certificate + final polish; ``'f64'`` = f64 throughout — both
-    need the caller's ``enable_x64`` scope); ``omega_on`` enables the
-    primal-weight split.  ``use_init`` is a *traced* bool selecting the
+    need the caller's ``jax.enable_x64(True)`` scope); ``omega_on``
+    enables the primal-weight split.  ``use_init`` is a *traced* bool selecting the
     warm arrays over the default init — the sweep pipeline's scan body
     passes it so cold group 0 and warm groups 1.. share one trace.
 
@@ -963,13 +969,11 @@ def solve_lp_many(problems, iters: int = 2000, step_scale: float = 0.9,
         primal, dual, rel_gap = (np.asarray(primal), np.asarray(dual),
                                  np.asarray(rel_gap))
     else:
-        from jax.experimental import enable_x64
-
         # the whole tol-mode call — array creation included — lives in
         # a scoped x64 context (place_step.py's discipline): f64 arrays
         # built outside it would silently downcast, and the jit cache
         # keys on the x64 flag so this never collides with f32 traces
-        with enable_x64():
+        with jax.enable_x64(True):
             x0 = y0 = eta_init = omega_init = None
             if init is not None:
                 x0, y0, eta_a, omega_a = _align_state(init, batch)
@@ -1077,16 +1081,15 @@ def _pipeline_fn(max_iters: int, check_every: int, Tp: int, operator: str,
         return outs + (carry[1].astype(jnp.float32),)
 
     if n_devices is not None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh
         from jax.sharding import PartitionSpec as P
 
         mesh = Mesh(np.array(jax.devices()[:n_devices]), ("lanes",))
         gb = P(None, "lanes")  # (G, B, ...) stacked group arrays
-        run = shard_map(run, mesh=mesh,
-                        in_specs=(gb, gb, gb, gb, gb, P(), P()),
-                        out_specs=(gb,) * 9 + (P("lanes"),),
-                        check_rep=False)
+        run = jax.shard_map(run, mesh=mesh,
+                            in_specs=(gb, gb, gb, gb, gb, P(), P()),
+                            out_specs=(gb,) * 9 + (P("lanes"),),
+                            check_vma=False)
     return jax.jit(run)
 
 
@@ -1117,9 +1120,7 @@ def _sweep_pipeline(groups, pad_to, tol, iters, step_scale, operator,
                 f"devices={devices} exceeds the {len(jax.devices())} "
                 f"local device(s)")
         n_devices = devices
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         w_dt = jnp.float64 if precision == "f64" else jnp.float32
         W = jnp.asarray(np.stack([bt.weights() for bt in batches]), w_dt)
         S = jnp.asarray(np.stack([bt.start for bt in batches]))

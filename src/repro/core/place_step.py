@@ -39,7 +39,7 @@ per-step tensors stay close to the work the numpy engine touches.
 Exactness.  Placements are bit-identical to ``two_phase`` and the
 numpy lockstep engine:
 
-  * the whole sub-phase is traced under ``jax.experimental.enable_x64``
+  * the whole sub-phase is traced under ``jax.enable_x64(True)``
     so every elementwise expression (feasibility comparisons against
     ``dem - EPS``, capacity subtractions ``rem - dem`` over the span,
     the ``rem / capx`` normalizations) is the same float64 operation on
@@ -511,7 +511,7 @@ def run_compiled(batch, phases, fit: str, filling: bool,
     independent scan lane).  filling=True runs wave-synchronized, one
     own-pack + one cross-fill dispatch per node-type phase boundary.
     """
-    from jax.experimental import enable_x64
+    import jax
 
     if max_pool_cells is None:
         max_pool_cells = MAX_POOL_CELLS
@@ -528,7 +528,7 @@ def run_compiled(batch, phases, fit: str, filling: bool,
         return None
 
     t0 = time.perf_counter()
-    with enable_x64():
+    with jax.enable_x64(True):
         if filling:
             wave_s, node_type = _run_waves(drv, filling)
             mode = "wave-sequential"

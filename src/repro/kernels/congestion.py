@@ -71,6 +71,7 @@ def _congestion_many_kernel(start_ref, end_ref, w_ref, out_ref, *, block_t):
     acc = jnp.dot(
         mask.astype(w_ref.dtype), w_ref[0],
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,  # f32 weights, not bf16
     )
     out_ref[...] += acc.astype(out_ref.dtype)[None]
 
@@ -100,9 +101,12 @@ def congestion_many_pallas(
     n_p = max(pl.cdiv(n, block_n) * block_n, block_n)
     K_p = max(pl.cdiv(K, block_k) * block_k, block_k)
     T_p = max(pl.cdiv(T, block_t) * block_t, block_t)
-    start_p = jnp.full((G, n_p), 1, jnp.int32).at[:, :n].set(
+    # bounds ride as (G, 1, n_p) so each block's last two dims are
+    # (1 = full, block_n), which the TPU's (8, 128) tiling rule accepts
+    # for any G; a (1, block_n) block of a (G, n_p) array does not
+    start_p = jnp.full((G, 1, n_p), 1, jnp.int32).at[:, 0, :n].set(
         start.astype(jnp.int32))
-    end_p = jnp.full((G, n_p), 0, jnp.int32).at[:, :n].set(
+    end_p = jnp.full((G, 1, n_p), 0, jnp.int32).at[:, 0, :n].set(
         end.astype(jnp.int32))
     w_p = jnp.zeros((G, n_p, K_p), dtype).at[:, :n, :K].set(w)
 
@@ -111,8 +115,8 @@ def congestion_many_pallas(
         functools.partial(_congestion_many_kernel, block_t=block_t),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_n), lambda g, i, j, k: (g, k)),
-            pl.BlockSpec((1, block_n), lambda g, i, j, k: (g, k)),
+            pl.BlockSpec((1, 1, block_n), lambda g, i, j, k: (g, 0, k)),
+            pl.BlockSpec((1, 1, block_n), lambda g, i, j, k: (g, 0, k)),
             pl.BlockSpec((1, block_n, block_k), lambda g, i, j, k: (g, k, j)),
         ],
         out_specs=pl.BlockSpec(

@@ -1,9 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU hosts (this container) the kernels run under ``interpret=True``,
-which executes the kernel body in Python for correctness; on TPU the same
-code lowers to Mosaic.  ``ref.py`` holds the pure-jnp oracles used by the
-test sweeps.
+On TPU the kernels lower to Mosaic.  On the CPU backend (the test
+suite, ``JAX_PLATFORMS=cpu``) they run under ``interpret=True``, which
+executes the kernel body in Python for correctness.  Any other backend
+is an error rather than a silent interpret-mode fallback.  ``ref.py``
+holds the pure-jnp oracles used by the test sweeps.
 """
 
 from __future__ import annotations
@@ -18,15 +19,22 @@ from . import congestion as _congestion
 from . import fit as _fit
 from . import ref
 
-__all__ = ["on_tpu", "congestion", "congestion_many", "fit_scores",
+__all__ = ["congestion", "congestion_many", "fit_scores",
            "fit_scores_many", "fit_scores_step"]
 
 _EPS = 1e-7
 
 
 @functools.lru_cache(maxsize=1)
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Pallas interpret mode: off on TPU, on for the CPU backend."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas kernels compile for TPU (or run interpreted on "
+            f"the CPU backend for tests); the default backend is "
+            f"{backend!r}")
+    return backend == "cpu"
 
 
 def congestion(start, end, w, T: int, use_ref: bool = False):
@@ -37,7 +45,7 @@ def congestion(start, end, w, T: int, use_ref: bool = False):
     if use_ref:
         return ref.congestion_ref(start, end, w, T)
     return _congestion.congestion_pallas(
-        start, end, w, T, interpret=not on_tpu()
+        start, end, w, T, interpret=_interpret()
     )
 
 
@@ -50,7 +58,7 @@ def congestion_many(start, end, w, T: int, use_ref: bool = False):
     if use_ref:
         return ref.congestion_many_ref(start, end, w, T)
     return _congestion.congestion_many_pallas(
-        start, end, w, T, interpret=not on_tpu()
+        start, end, w, T, interpret=_interpret()
     )
 
 
@@ -78,7 +86,7 @@ def fit_scores(rem, dem, s: int, e: int, cap, scored: bool = False,
         rem_tdn = jnp.asarray(np.ascontiguousarray(rem.transpose(1, 2, 0)),
                               jnp.float32)
         feas_m, dot, norm2 = _fit.fit_scores_pallas(
-            rem_tdn, dem_j, mask, inv_cap, interpret=not on_tpu()
+            rem_tdn, dem_j, mask, inv_cap, interpret=_interpret()
         )
     feas = np.asarray(feas_m) >= -_EPS
     if not scored:
@@ -123,7 +131,7 @@ def fit_scores_many(rem, dem, s, e, inv_cap, scored: bool = False,
             np.ascontiguousarray(rem.transpose(0, 2, 3, 1)), jnp.float32)
         feas_m, dot, norm2 = _fit.fit_scores_many_pallas(
             rem_btdn, dem_j, jnp.asarray(mask), inv_j,
-            interpret=not on_tpu()
+            interpret=_interpret()
         )
     feas = np.asarray(feas_m) >= -_EPS
     if not scored:
@@ -171,7 +179,7 @@ def fit_scores_step(rem, dem, span, capx, dem_norm, scored: bool = False,
     elementwise float comparison the host engines evaluate
     (``not any(rem < dem - eps)`` over the span), and ``score`` is the
     quantized cosine similarity (zeros when ``scored`` is False).  In a
-    float64 trace (``jax.experimental.enable_x64``) every elementwise
+    float64 trace (``jax.enable_x64(True)``) every elementwise
     term is bit-identical to the numpy engines; the reduction sums may
     differ in the last ulp, which the shared quantization collapses.
     """
@@ -183,7 +191,8 @@ def fit_scores_step(rem, dem, span, capx, dem_norm, scored: bool = False,
     span_f = span.astype(rem.dtype)
     rem_n = rem / capx[:, None, :]
     q = (dem / capx) * span_f                 # exact: dem_n * {0, 1}
-    dot = jnp.einsum("bnk,bk->bn", rem_n, q)  # batched mat-vec
+    dot = jnp.einsum("bnk,bk->bn", rem_n, q,  # batched mat-vec
+                     precision=jax.lax.Precision.HIGHEST)
     rm = rem_n * span_f[:, None, :]
     norm2 = (rm * rm).sum(axis=2)
     score = dot / (dem_norm[:, None] * jnp.sqrt(norm2) + 1e-30)

@@ -3,6 +3,9 @@
 These are the ground truth every kernel test compares against
 (``assert_allclose`` over shape/dtype sweeps).
 
+Every contraction runs at ``Precision.HIGHEST``, so an oracle is
+exact f32 on every backend (the TPU's default is one bf16 pass).
+
 Like the kernels, every oracle is generic over the trailing feature
 dimensions (D/K): lowered virtual constraint columns from
 ``repro.core.constraints`` (exclusivity, anti-affinity) are ordinary
@@ -11,10 +14,13 @@ capacity dimensions here and need no special casing.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 __all__ = ["congestion_ref", "congestion_many_ref", "fit_scores_ref",
            "fit_scores_many_ref"]
+
+_HI = jax.lax.Precision.HIGHEST
 
 
 def congestion_ref(start, end, w, T: int):
@@ -26,7 +32,7 @@ def congestion_ref(start, end, w, T: int):
     """
     t = jnp.arange(T, dtype=jnp.int32)
     mask = (start[None, :] <= t[:, None]) & (t[:, None] <= end[None, :])
-    return mask.astype(w.dtype) @ w
+    return jnp.matmul(mask.astype(w.dtype), w, precision=_HI)
 
 
 def congestion_many_ref(start, end, w, T: int):
@@ -38,7 +44,8 @@ def congestion_many_ref(start, end, w, T: int):
     t = jnp.arange(T, dtype=jnp.int32)
     mask = (start[:, None, :] <= t[None, :, None]) \
         & (t[None, :, None] <= end[:, None, :])  # (G, T, n)
-    return jnp.einsum("gtn,gnk->gtk", mask.astype(w.dtype), w)
+    return jnp.einsum("gtn,gnk->gtk", mask.astype(w.dtype), w,
+                      precision=_HI)
 
 
 def fit_scores_ref(rem, dem, mask, inv_cap):
@@ -61,8 +68,9 @@ def fit_scores_ref(rem, dem, mask, inv_cap):
     feas_margin = masked_margin.min(axis=(1, 2))
     rem_n = rem * inv_cap[None, None, :]
     dem_n = dem * inv_cap
-    dot = jnp.einsum("ntd,d,t->n", rem_n, dem_n, mask)
-    rem_norm2 = jnp.einsum("ntd,ntd,t->n", rem_n, rem_n, mask)
+    dot = jnp.einsum("ntd,d,t->n", rem_n, dem_n, mask, precision=_HI)
+    rem_norm2 = jnp.einsum("ntd,ntd,t->n", rem_n, rem_n, mask,
+                           precision=_HI)
     return feas_margin, dot, rem_norm2
 
 
@@ -89,6 +97,7 @@ def fit_scores_many_ref(rem, dem, mask, inv_cap):
     feas_margin = masked_margin.min(axis=(2, 3))
     rem_n = rem * inv_cap[:, None, None, :]
     dem_n = dem * inv_cap
-    dot = jnp.einsum("bntd,bd,bt->bn", rem_n, dem_n, mask)
-    rem_norm2 = jnp.einsum("bntd,bntd,bt->bn", rem_n, rem_n, mask)
+    dot = jnp.einsum("bntd,bd,bt->bn", rem_n, dem_n, mask, precision=_HI)
+    rem_norm2 = jnp.einsum("bntd,bntd,bt->bn", rem_n, rem_n, mask,
+                           precision=_HI)
     return feas_margin, dot, rem_norm2

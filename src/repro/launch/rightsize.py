@@ -43,6 +43,7 @@ from repro.core import (
     rightsize,
     trim_timeline,
 )
+from repro.launch import enable_compile_cache
 from repro.workload.jobs import DEFAULT_SCHEDULE, fleet_problem
 
 
@@ -370,6 +371,7 @@ def run(argv=None):
     args = ap.parse_args(argv)
     if args.command is None:
         args = ap.parse_args(["plan"] + (argv or []))
+    enable_compile_cache()
     return args.func(args)
 
 

@@ -123,6 +123,31 @@ class TestFitKernel:
                                        rtol=1e-4, atol=1e-5)
 
 
+class TestInterpretMode:
+    """Interpret mode only on the CPU backend; no silent fallback on
+    any other."""
+
+    @pytest.fixture
+    def backend(self, monkeypatch):
+        def use(name):
+            monkeypatch.setattr(ops.jax, "default_backend", lambda: name)
+            ops._interpret.cache_clear()
+        yield use
+        ops._interpret.cache_clear()
+
+    @pytest.mark.parametrize("name,interpret", [("cpu", True),
+                                                ("tpu", False)])
+    def test_cpu_interprets_tpu_compiles(self, backend, name, interpret):
+        backend(name)
+        assert ops._interpret() is interpret
+
+    def test_other_backend_raises(self, backend):
+        backend("gpu")
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ops.congestion(np.array([0]), np.array([0]),
+                           np.ones((1, 1), np.float32), 1)
+
+
 class TestBackendParity:
     @pytest.mark.slow
     def test_placement_identical_across_backends(self):
