@@ -25,14 +25,12 @@ restarts, final KKT residuals for vanilla vs adaptive vs warm-started
 solves), written next to the timing output as
 ``<out>/solver_stats.json`` — the file the CI convergence-
 regression gate (benchmarks/check_convergence.py) diffs against
-``results/golden/solver_stats.json``.  Roofline rows (from dry-run
-artifacts, if present) are appended at the end.
+``results/golden/solver_stats.json``.
 """
 
 from __future__ import annotations
 
 import argparse
-import glob
 import inspect
 import json
 import os
@@ -83,7 +81,6 @@ def main(argv=None) -> None:
                          "'serve' key of <out>/solver_stats.json")
     ap.add_argument("--only", default="")
     ap.add_argument("--out", default="results/paper")
-    ap.add_argument("--dryrun-dir", default="results/dryrun")
     args = ap.parse_args(argv)
 
     if args.buckets is not None and args.buckets < 1:
@@ -147,20 +144,6 @@ def main(argv=None) -> None:
               f"dispatches_per_tick={blob['dispatches_per_tick']}")
         print(f"serve_trace,_wall_s={time.perf_counter() - t0:.1f}",
               flush=True)
-
-    # roofline table from dry-run artifacts, where a dry run wrote them
-    if not glob.glob(os.path.join(args.dryrun_dir, "*.json")):
-        print(f"# roofline skipped: no dry-run artifacts in "
-              f"{args.dryrun_dir}")
-        return
-    from benchmarks.roofline import fmt_table, table
-
-    rows = table(args.dryrun_dir, mesh="16x16")
-    if rows:
-        print("\n# Roofline (16x16, from dry-run artifacts)")
-        print(fmt_table(rows))
-        with open(os.path.join(args.out, "roofline.json"), "w") as f:
-            json.dump(rows, f, indent=1)
 
 
 if __name__ == "__main__":

@@ -573,6 +573,11 @@ def _tol_core(w_all, start, end, feas, cost, step_scale, tol,
     warm arrays over the default init — the sweep pipeline's scan body
     passes it so cold group 0 and warm groups 1.. share one trace.
 
+    Each phase runs under a ``jax.named_scope`` (``ruiz``,
+    ``operators``, ``power``, ``pdhg``, ``certificate``, ``polish``,
+    ``unscale``), so the device trace can tell them apart; scopes change
+    op metadata only, never the ops.
+
     This function is deliberately un-jitted: ``_pdhg_run_many_tol``
     wraps it for the one-batch entry point and ``_pipeline_fn`` scans it
     over sweep groups inside one jit.
@@ -586,16 +591,19 @@ def _tol_core(w_all, start, end, feas, cost, step_scale, tol,
     cost = cost.astype(it_dt)
 
     if scaling == "ruiz":
-        c_sc, r_sc = _ruiz_scalings(w_all)
-        ws_all = w_all * (r_sc[:, None, :, None] / c_sc[:, :, None, None])
-        cost_s = cost / r_sc   # scaled dual caps (padded types stay huge)
-        mass = c_sc            # scaled primal simplex masses
+        with jax.named_scope("ruiz"):
+            c_sc, r_sc = _ruiz_scalings(w_all)
+            ws_all = w_all * (r_sc[:, None, :, None] / c_sc[:, :, None, None])
+            cost_s = cost / r_sc   # scaled dual caps (padded types stay huge)
+            mass = c_sc            # scaled primal simplex masses
     else:
         ws_all, cost_s, mass = w_all, cost, None
 
-    fwd_all, adj_all = _make_operators(ws_all, start, end, Tp, operator)
-    op_norm = _power_op_norm(fwd_all, adj_all, feas,
-                             power_iters).astype(it_dt)
+    with jax.named_scope("operators"):
+        fwd_all, adj_all = _make_operators(ws_all, start, end, Tp, operator)
+    with jax.named_scope("power"):
+        op_norm = _power_op_norm(fwd_all, adj_all, feas,
+                                 power_iters).astype(it_dt)
     eta0 = step_scale / (op_norm + 1e-30)                     # (B,)
     cap = cost_s[:, None, :, None]
 
@@ -782,57 +790,62 @@ def _tol_core(w_all, start, end, feas, cost, step_scale, tol,
         sum_Ax=jnp.zeros_like(Ax), elen=zeros_b,
         dxs=zeros_b, dys=zeros_b,
     )
-    c = jax.lax.while_loop(cond, body, c)
+    with jax.named_scope("pdhg"):
+        c = jax.lax.while_loop(cond, body, c)
 
     if precision == "mixed":
         # f64 certificate with f64 *weights* (the in-loop checks only
         # widen the accumulation), then a short plain-PDHG polish at the
         # adapted per-lane step split, kept per lane only where it
         # tightens the certified gap — kkt can only improve.
-        pol_op = "cumsum" if operator == "pallas" else operator
-        fwd64, adj64 = _make_operators(ws_all.astype(cert_dt), start, end,
-                                       Tp, pol_op)
-        x_fin = c.x.astype(cert_dt)
-        y_fin = c.y.astype(cert_dt)
-        primal, dual, rel_gap = _objectives(fwd64(x_fin), y_fin, adj64,
-                                            cost_s, feas, mass=mass,
-                                            dt=cert_dt)
-        cap64 = cap.astype(cert_dt)
-        mass64 = None if mass is None else mass.astype(cert_dt)
-        if omega_on:
-            sig_p = (c.eta * c.omega).astype(cert_dt)[:, None, None, None]
-            tau_p = (c.eta / c.omega).astype(cert_dt)[:, None, None]
-        else:
-            sig_p = c.eta.astype(cert_dt)[:, None, None, None]
-            tau_p = c.eta.astype(cert_dt)[:, None, None]
+        with jax.named_scope("certificate"):
+            pol_op = "cumsum" if operator == "pallas" else operator
+            fwd64, adj64 = _make_operators(ws_all.astype(cert_dt), start, end,
+                                           Tp, pol_op)
+            x_fin = c.x.astype(cert_dt)
+            y_fin = c.y.astype(cert_dt)
+            primal, dual, rel_gap = _objectives(fwd64(x_fin), y_fin, adj64,
+                                                cost_s, feas, mass=mass,
+                                                dt=cert_dt)
+        with jax.named_scope("polish"):
+            cap64 = cap.astype(cert_dt)
+            mass64 = None if mass is None else mass.astype(cert_dt)
+            if omega_on:
+                sig_p = (c.eta * c.omega).astype(cert_dt)[:, None, None, None]
+                tau_p = (c.eta / c.omega).astype(cert_dt)[:, None, None]
+            else:
+                sig_p = c.eta.astype(cert_dt)[:, None, None, None]
+                tau_p = c.eta.astype(cert_dt)[:, None, None]
 
-        def pstep(carry, _):
-            xp, yp, xpr = carry
-            y_n = _project_capped_simplex_td(
-                yp + sig_p * fwd64(2.0 * xp - xpr), cap64)
-            x_n = _project_simplex_masked(xp - tau_p * adj64(y_n), feas,
-                                          mass64)
-            return (x_n, y_n, xp), None
+            def pstep(carry, _):
+                xp, yp, xpr = carry
+                y_n = _project_capped_simplex_td(
+                    yp + sig_p * fwd64(2.0 * xp - xpr), cap64)
+                x_n = _project_simplex_masked(xp - tau_p * adj64(y_n), feas,
+                                              mass64)
+                return (x_n, y_n, xp), None
 
-        (x_p, y_p, _), _ = jax.lax.scan(pstep, (x_fin, y_fin, x_fin),
-                                        None, length=_POLISH_ITERS)
-        p_p, d_p, r_p = _objectives(fwd64(x_p), y_p, adj64, cost_s, feas,
-                                    mass=mass, dt=cert_dt)
-        better = r_p < rel_gap
-        x_fin = jnp.where(better[:, None, None], x_p, x_fin)
-        y_fin = jnp.where(better[:, None, None, None], y_p, y_fin)
-        primal = jnp.where(better, p_p, primal)
-        dual = jnp.where(better, d_p, dual)
-        rel_gap = jnp.where(better, r_p, rel_gap)
+            (x_p, y_p, _), _ = jax.lax.scan(pstep, (x_fin, y_fin, x_fin),
+                                            None, length=_POLISH_ITERS)
+            p_p, d_p, r_p = _objectives(fwd64(x_p), y_p, adj64, cost_s, feas,
+                                        mass=mass, dt=cert_dt)
+            better = r_p < rel_gap
+            x_fin = jnp.where(better[:, None, None], x_p, x_fin)
+            y_fin = jnp.where(better[:, None, None, None], y_p, y_fin)
+            primal = jnp.where(better, p_p, primal)
+            dual = jnp.where(better, d_p, dual)
+            rel_gap = jnp.where(better, r_p, rel_gap)
     else:
         x_fin, y_fin = c.x, c.y
-        primal, dual, rel_gap = _objectives(c.Ax, c.y, adj_all, cost_s,
-                                            feas, mass=mass, dt=cert_dt)
+        with jax.named_scope("certificate"):
+            primal, dual, rel_gap = _objectives(c.Ax, c.y, adj_all, cost_s,
+                                                feas, mass=mass, dt=cert_dt)
 
     if scaling == "ruiz":
         # back to original coordinates — callers never see the scales
-        x_fin = x_fin / c_sc[:, :, None]
-        y_fin = y_fin * r_sc[:, None, :, None]
+        with jax.named_scope("unscale"):
+            x_fin = x_fin / c_sc[:, :, None]
+            y_fin = y_fin * r_sc[:, None, :, None]
     return (x_fin, y_fin, primal, dual, rel_gap, c.iters_b, c.restarts_b,
             c.conv, c.eta, c.omega)
 

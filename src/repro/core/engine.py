@@ -57,6 +57,7 @@ from .placement import FIT_POLICIES, two_phase
 from .constraints import expand_solution, lower_constraints
 from .problem import Problem, trim_timeline
 from .solution import Solution, verify
+from .spans import span
 
 __all__ = [
     "SolverConfig", "PlacementConfig", "SweepConfig", "FleetEngine",
@@ -553,11 +554,15 @@ class FleetResult:
 def _protocol_batched(batch: ProblemBatch, lp_results, algos, fits,
                       backend: str, check: bool = True,
                       stepper: str = "lockstep",
-                      tels: list | None = None) -> list[dict]:
+                      tels: list | None = None,
+                      timings: dict | None = None) -> list[dict]:
     """Batched placement protocol: every (mapping, fit, filling) combo of
     every algorithm runs as ONE lockstep ``place_many`` over the grid
     (through the ``stepper`` of the configured placement engine);
-    per-call stepper telemetry is appended to ``tels``."""
+    per-call stepper telemetry is appended to ``tels``, and the seconds
+    spent verifying kept plans are added to ``timings["verify_s"]``.
+    Each pass is a ``repro.place.pass`` span carrying its lockstep step
+    count and wave seconds as trace metadata."""
     from .api import rightsize
 
     B = batch.B
@@ -584,9 +589,13 @@ def _protocol_batched(batch: ProblemBatch, lp_results, algos, fits,
         for maps in mapsets:
             for fit in fits:
                 tel: dict = {}
-                sols = place_many(batch, maps, fit=fit, filling=filling,
-                                  backend=backend, meta={"algo": algo},
-                                  placement=stepper, telemetry=tel)
+                with span("place.pass") as ann:
+                    sols = place_many(batch, maps, fit=fit,
+                                      filling=filling, backend=backend,
+                                      meta={"algo": algo},
+                                      placement=stepper, telemetry=tel)
+                    ann.set_metadata(steps=tel.get("steps", 0),
+                                     wave_s=sum(tel.get("wave_s", ())))
                 if tels is not None:
                     tels.append(tel)
                 for b, (t, s) in enumerate(zip(batch.problems, sols)):
@@ -596,7 +605,8 @@ def _protocol_batched(batch: ProblemBatch, lp_results, algos, fits,
         wall = (time.perf_counter() - t0) / B
         for b, t in enumerate(batch.problems):
             if check:
-                verify(t, best[b])
+                with span("verify", timings, "verify_s"):
+                    verify(t, best[b])
             out[b]["costs"][algo] = best_cost[b]
             out[b]["wall_s"][algo] = wall
     for entry in out:
@@ -610,12 +620,14 @@ def _placement_telemetry(engine: str, tels: list) -> dict:
     """Aggregate per-call stepper telemetry into the ``FleetResult``
     timings block: which stepper actually ran, how many device
     dispatches the compiled stepper issued, how often it fell back,
-    and the summed per-phase (wave) seconds."""
+    the summed per-phase (wave) seconds, and the numpy lockstep
+    engine's step count."""
     out: dict = {"engine": engine, "calls": len(tels)}
     if engine == "loop" or not tels:
         return out
     out["waves"] = max((t.get("waves", 0) for t in tels), default=0)
     out["wave_s_total"] = sum(sum(t.get("wave_s", ())) for t in tels)
+    out["steps"] = sum(t.get("steps", 0) for t in tels)
     if engine == "compiled":
         out["dispatches"] = sum(t.get("dispatches", 0) for t in tels)
         out["fallbacks"] = sum(1 for t in tels
@@ -964,7 +976,8 @@ class FleetEngine:
         return sols
 
     def _evaluate_bucket(self, batch: ProblemBatch, lp_results,
-                         tels: list | None = None):
+                         tels: list | None = None,
+                         timings: dict | None = None):
         """§VI protocol entries for one packed bucket."""
         cfg = self.placement
         if cfg.engine in _ENGINE_STEPPER:
@@ -972,7 +985,7 @@ class FleetEngine:
                                      cfg.fits, cfg.backend,
                                      check=cfg.check,
                                      stepper=_ENGINE_STEPPER[cfg.engine],
-                                     tels=tels)
+                                     tels=tels, timings=timings)
         from .api import _protocol_entry
 
         return [_protocol_entry(t, res, res.lower_bound, self.algos,
@@ -984,68 +997,60 @@ class FleetEngine:
     def evaluate(self, problems) -> FleetResult:
         """§VI protocol over a fleet: bucketed pack -> per-bucket LP
         solve -> per-bucket lockstep placement -> entries merged back
-        into submission order, as a ``FleetResult``."""
-        t_start = time.perf_counter()
-        if self.sweep.warm_start is not None:
-            return self._evaluate_warm(problems, t_start)
-        t0 = time.perf_counter()
-        plan = problems if isinstance(problems, PackPlan) \
-            else self.pack(problems)
-        pack_s = time.perf_counter() - t0
+        into submission order, as a ``FleetResult``.  Each phase is a
+        ``repro.*`` span (``repro.core.spans``) whose seconds go to
+        ``timings``."""
+        timings = {"verify_s": 0.0}
+        with span("evaluate", timings, "total_s"):
+            if self.sweep.warm_start is not None:
+                return self._evaluate_warm(problems, timings)
+            with span("pack", timings, "pack_s"):
+                plan = problems if isinstance(problems, PackPlan) \
+                    else self.pack(problems)
+            entries: list[dict | None] = [None] * plan.n_instances
+            stats: list[SolveStats] = []
+            bucket_lp_s, bucket_place_s = [], []
+            tels: list[dict] = []
+            for bucket in plan.buckets:
+                clock: dict = {}
+                with span("lp", clock, "lp_s"):
+                    lp_results, st = self._solve_bucket(bucket)
+                stats.extend(st)
+                with span("place", clock, "place_s"):
+                    part = self._evaluate_bucket(bucket.batch, lp_results,
+                                                 tels=tels, timings=timings)
+                bucket_lp_s.append(clock["lp_s"])
+                bucket_place_s.append(clock["place_s"])
+                if self.solver.tol is not None:
+                    self._attach_solver(part, lp_results)
+                for i, entry in zip(bucket.indices, part):
+                    entries[i] = entry
+            timings.update(
+                lp_s=sum(bucket_lp_s), place_s=sum(bucket_place_s),
+                bucket_lp_s=bucket_lp_s, bucket_place_s=bucket_place_s,
+                placement=_placement_telemetry(self.placement.engine,
+                                               tels))
+            return FleetResult(entries=entries, stats=stats, plan=plan,
+                               timings=timings)
 
-        entries: list[dict | None] = [None] * plan.n_instances
-        stats: list[SolveStats] = []
-        bucket_lp_s, bucket_place_s = [], []
-        tels: list[dict] = []
-        for bucket in plan.buckets:
-            t0 = time.perf_counter()
-            lp_results, st = self._solve_bucket(bucket)
-            bucket_lp_s.append(time.perf_counter() - t0)
-            stats.extend(st)
-            t0 = time.perf_counter()
-            part = self._evaluate_bucket(bucket.batch, lp_results,
-                                         tels=tels)
-            bucket_place_s.append(time.perf_counter() - t0)
-            if self.solver.tol is not None:
-                self._attach_solver(part, lp_results)
-            for i, entry in zip(bucket.indices, part):
-                entries[i] = entry
-        timings = {
-            "pack_s": pack_s,
-            "lp_s": sum(bucket_lp_s),
-            "place_s": sum(bucket_place_s),
-            "bucket_lp_s": bucket_lp_s,
-            "bucket_place_s": bucket_place_s,
-            "placement": _placement_telemetry(self.placement.engine,
-                                              tels),
-            "total_s": time.perf_counter() - t_start,
-        }
-        return FleetResult(entries=entries, stats=stats, plan=plan,
-                           timings=timings)
-
-    def _evaluate_warm(self, problems, t_start: float) -> FleetResult:
+    def _evaluate_warm(self, problems, timings: dict) -> FleetResult:
         """The warm-started sweep path: one chained LP solve, then one
         single-shape placement pass over the whole grid."""
         trimmed = self._trimmed(problems)
-        t0 = time.perf_counter()
-        lp_results, stats = self._solve_warm(trimmed)
-        lp_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        batch = problems if isinstance(problems, ProblemBatch) \
-            else pack_problems(trimmed, assume_trimmed=True)
-        pack_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
+        with span("lp", timings, "lp_s"):
+            lp_results, stats = self._solve_warm(trimmed)
+        with span("pack", timings, "pack_s"):
+            batch = problems if isinstance(problems, ProblemBatch) \
+                else pack_problems(trimmed, assume_trimmed=True)
         tels: list[dict] = []
-        entries = self._evaluate_bucket(batch, lp_results, tels=tels)
-        place_s = time.perf_counter() - t0
+        with span("place", timings, "place_s"):
+            entries = self._evaluate_bucket(batch, lp_results, tels=tels,
+                                            timings=timings)
         self._attach_solver(entries, lp_results)
-        timings = {
-            "pack_s": pack_s, "lp_s": lp_s, "place_s": place_s,
-            "bucket_lp_s": [lp_s], "bucket_place_s": [place_s],
-            "placement": _placement_telemetry(self.placement.engine,
-                                              tels),
-            "total_s": time.perf_counter() - t_start,
-        }
+        timings.update(
+            bucket_lp_s=[timings["lp_s"]],
+            bucket_place_s=[timings["place_s"]],
+            placement=_placement_telemetry(self.placement.engine, tels))
         return FleetResult(entries=entries, stats=stats, plan=None,
                            timings=timings)
 

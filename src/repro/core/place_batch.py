@@ -167,6 +167,7 @@ class _Engine:
         self.placed = np.zeros((Bn, batch.n), bool)
         self.assign = np.full((Bn, batch.n), -1, np.int64)
         self.dn, self.capx_all, self.span_all = _batch_aux(batch, phases)
+        self.steps = 0  # lockstep iterations over every sub-phase
 
     def run_wave(self, k: int, fit: str, filling: bool) -> bool:
         """Own-pack + cross-fill sub-phases of every instance's k-th
@@ -406,6 +407,7 @@ class _Engine:
                     self.counts[b_sel] - wl[place_a] + j_all
                 self.placed[b_sel, u_sel] = True
             ptr += alive
+            self.steps += 1
         self._pool = pool
 
 def place_many(problems, mappings, fit: str = "first",
@@ -431,7 +433,8 @@ def place_many(problems, mappings, fit: str = "first",
     the batch-dim-aware Pallas fit kernel); the compiled stepper scores
     on-device and ignores it.  ``telemetry``, when a dict, is filled
     in place with the stepper actually used, wave count, per-wave
-    seconds, and (compiled) device-dispatch counts.
+    seconds, the numpy engine's lockstep step count, and (compiled)
+    device-dispatch counts.
 
     >>> import numpy as np
     >>> from repro.core import place_many, two_phase
@@ -482,6 +485,7 @@ def place_many(problems, mappings, fit: str = "first",
         telemetry.setdefault("engine", "lockstep")
         telemetry["waves"] = len(wave_s)
         telemetry["wave_s"] = wave_s
+        telemetry["steps"] = eng.steps
 
     out = []
     for b, t in enumerate(batch.problems):
