@@ -562,7 +562,8 @@ def _protocol_batched(batch: ProblemBatch, lp_results, algos, fits,
     per-call stepper telemetry is appended to ``tels``, and the seconds
     spent verifying kept plans are added to ``timings["verify_s"]``.
     Each pass is a ``repro.place.pass`` span carrying its lockstep step
-    count and wave seconds as trace metadata."""
+    count, wave seconds and timeline slots read (windowed and not) as
+    trace metadata."""
     from .api import rightsize
 
     B = batch.B
@@ -594,8 +595,11 @@ def _protocol_batched(batch: ProblemBatch, lp_results, algos, fits,
                                       filling=filling, backend=backend,
                                       meta={"algo": algo},
                                       placement=stepper, telemetry=tel)
-                    ann.set_metadata(steps=tel.get("steps", 0),
-                                     wave_s=sum(tel.get("wave_s", ())))
+                    ann.set_metadata(
+                        steps=tel.get("steps", 0),
+                        wave_s=sum(tel.get("wave_s", ())),
+                        window_slots=tel.get("window_slots", 0),
+                        slots=tel.get("slots", 0))
                 if tels is not None:
                     tels.append(tel)
                 for b, (t, s) in enumerate(zip(batch.problems, sols)):
@@ -621,13 +625,15 @@ def _placement_telemetry(engine: str, tels: list) -> dict:
     timings block: which stepper actually ran, how many device
     dispatches the compiled stepper issued, how often it fell back,
     the summed per-phase (wave) seconds, and the numpy lockstep
-    engine's step count."""
+    engine's step count and timeline slots read (``window_slots``, of
+    ``slots``)."""
     out: dict = {"engine": engine, "calls": len(tels)}
     if engine == "loop" or not tels:
         return out
     out["waves"] = max((t.get("waves", 0) for t in tels), default=0)
     out["wave_s_total"] = sum(sum(t.get("wave_s", ())) for t in tels)
-    out["steps"] = sum(t.get("steps", 0) for t in tels)
+    for key in ("steps", "window_slots", "slots"):
+        out[key] = sum(t.get(key, 0) for t in tels)
     if engine == "compiled":
         out["dispatches"] = sum(t.get("dispatches", 0) for t in tels)
         out["fallbacks"] = sum(1 for t in tels

@@ -13,6 +13,9 @@ hot loop of this whole family of vector bin-packing heuristics) instead
 of B Python-level ``TypePool.find_fit`` calls.  All per-step bookkeeping
 (schedule pointers, purchases, capacity updates) is vectorized across
 instances, so a step costs O(1) numpy dispatches regardless of B.
+Each step reads and writes only its window of the time axis, the live
+tasks' span union ``[t0, t1)``, not all T' slots: a task's span is
+typically a small part of the trimmed timeline.
 
 Wave synchronization is the engine's load-bearing trick: instances are
 independent, so inserting barriers between their (own-pack, cross-fill)
@@ -45,11 +48,17 @@ properties make that hold:
     EPS)`` over the span (a bool reduction of the identical
     comparisons, on identical remaining-capacity values — elementwise
     updates never reassociate), and similarity via einsums whose masked
-    terms are exact zeros.  Similarity sums can still differ from the
-    loop in the last ulp (numpy's einsum kernels vary with memory
-    layout), so BOTH engines quantize scores to 9 decimals before the
-    argmax — reassociation noise collapses onto identical values and
-    the first-max tie-break picks the same node on every path.
+    terms are exact zeros.  The step window changes none of it: a slot
+    outside ``[t0, t1)`` lies outside every live task's span, so the
+    full-timeline pass masks it out of feasibility, adds an exact zero
+    for it to the einsums, and subtracts ``dem*0`` from it, no change.
+    A node bought in the step is set full over the whole timeline (and
+    so is its cached ``rem / cap`` row) before the windowed update.
+    Similarity sums can still differ from the loop in the last ulp
+    (numpy's einsum kernels vary with memory layout), so BOTH engines
+    quantize scores to 9 decimals before the argmax — reassociation
+    noise collapses onto identical values and the first-max tie-break
+    picks the same node on every path.
 
 ``backend='kernel'`` routes the scoring pass through the batch-dim-aware
 Pallas kernel ``fit_scores_many`` (grid over B; fp32, matching the
@@ -168,6 +177,10 @@ class _Engine:
         self.assign = np.full((Bn, batch.n), -1, np.int64)
         self.dn, self.capx_all, self.span_all = _batch_aux(batch, phases)
         self.steps = 0  # lockstep iterations over every sub-phase
+        # timeline slots the steps read (their windows) and would have
+        # read without windows (T' each)
+        self.window_slots = 0
+        self.slots = 0
 
     def run_wave(self, k: int, fit: str, filling: bool) -> bool:
         """Own-pack + cross-fill sub-phases of every instance's k-th
@@ -270,8 +283,9 @@ class _Engine:
 
             inv_cap = np.where(np.isfinite(capx), 1.0 / capx, 0.0)
         # pool_n caches pool / capx so similarity steps skip the big
-        # division pass; one row is re-divided after each update, which
-        # is bitwise what find_fit computes from the current rem
+        # division pass; the window of each updated row is re-divided
+        # after the update, which is bitwise what find_fit computes from
+        # the current rem
         pool_n = pool_l / capx[:, None, None, :] \
             if similarity and not kernel else None
 
@@ -326,7 +340,14 @@ class _Engine:
             dem = batch.dem[bsel_l, u_cur]               # (A, Dp)
             s_cur = start_pad[bsel_l, u_cur]
             e_cur = end_pad[bsel_l, u_cur]
-            span = self.span_all[bsel_l, u_cur]          # (A, T')
+            # the step's window: the live rows' span union [t0, t1).
+            # Every slot outside it lies outside every live task's span,
+            # so the step reads and writes only the window
+            t0 = int(s_cur[alive].min())
+            t1 = int(e_cur[alive].max()) + 1
+            self.window_slots += t1 - t0
+            self.slots += batch.Tp
+            span = self.span_all[bsel_l, u_cur, t0:t1]  # (A, t1 - t0)
             W = max(int(wl.max()), 1)
             node_ok = (np.arange(W)[None, :] < wl[:, None]) \
                 & alive[:, None]
@@ -338,25 +359,23 @@ class _Engine:
                 feas = feas & node_ok
             else:
                 # not any(rem < dem - EPS) over the span == find_fit's
-                # all(rem >= dem - EPS): the same comparisons on the
-                # contiguous (T'*D)-flattened pool rows (numpy's
+                # all(rem >= dem - EPS): the same comparisons on each
+                # node's contiguous ((t1-t0)*D)-flattened window (numpy's
                 # iterator is ~10x faster there than on 4-D broadcasts
-                # with a tiny trailing axis)
-                pool3 = pool_l[:, :W].reshape(A, W, -1)  # contig view
-                thr_flat = np.tile(dem - EPS, (1, batch.Tp))
+                # with a tiny trailing axis); the slots the window drops
+                # are masked out of every live row's span anyway
+                pool3 = pool_l[:, :W, t0:t1].reshape(A, W, -1)  # a view
+                thr_flat = np.tile(dem - EPS, (1, t1 - t0))
                 span_flat = np.repeat(span, batch.D, axis=1)
                 viol = ((pool3 < thr_flat[:, None, :])
                         & span_flat[:, None, :]).any(axis=2)
                 feas = ~viol & node_ok
                 if similarity:
-                    # slice time to the live span union for the einsum
-                    # reductions: dropped slots carry only exact-zero
-                    # terms, so the accumulations are unchanged
-                    t0 = int(s_cur[alive].min())
-                    t1 = int(e_cur[alive].max()) + 1
+                    # the window's dropped slots carry only exact-zero
+                    # terms, so the einsum accumulations are unchanged
                     rem_n = pool_n[:, :W, t0:t1]
                     dem_n = dem / capx
-                    span_f = span[:, t0:t1].astype(np.float64)
+                    span_f = span.astype(np.float64)
                     dot = np.einsum("bntd,bd,bt->bn", rem_n, dem_n,
                                     span_f)
                     norm2 = np.einsum("bntd,bntd,bt->bn", rem_n, rem_n,
@@ -387,7 +406,13 @@ class _Engine:
                             f"to node-type {int(tau_l[a0])} it cannot "
                             f"fit")
                     j_new = wl[buy_a]
+                    # a new node is full over the whole timeline, and so
+                    # is its cached pool / capx row; the update below
+                    # then touches only the window of either
                     pool_l[buy_a, j_new] = cap_rows[buy_a][:, None]
+                    if pool_n is not None:
+                        pool_n[buy_a, j_new] = (
+                            cap_rows[buy_a] / capx[buy_a])[:, None]
                     wl[buy_a] += 1
                     self.counts[bsel_l[buy_a]] += 1
                     place_a = np.concatenate([place_a, buy_a])
@@ -395,10 +420,12 @@ class _Engine:
             if len(place_a):
                 sub = (dem[place_a][:, None, :]
                        * span[place_a].astype(np.float64)[:, :, None])
-                pool_l[place_a, j_all] -= sub  # dem*1 / dem*0: exact
+                # dem*1 / dem*0 inside the window: exact; outside it the
+                # task's span is empty, so those slots stay as they were
+                pool_l[place_a, j_all, t0:t1] -= sub
                 if pool_n is not None:
-                    pool_n[place_a, j_all] = (
-                        pool_l[place_a, j_all]
+                    pool_n[place_a, j_all, t0:t1] = (
+                        pool_l[place_a, j_all, t0:t1]
                         / capx[place_a][:, None, :])
                 u_sel = u_cur[place_a]
                 b_sel = bsel_l[place_a]
@@ -433,8 +460,9 @@ def place_many(problems, mappings, fit: str = "first",
     the batch-dim-aware Pallas fit kernel); the compiled stepper scores
     on-device and ignores it.  ``telemetry``, when a dict, is filled
     in place with the stepper actually used, wave count, per-wave
-    seconds, the numpy engine's lockstep step count, and (compiled)
-    device-dispatch counts.
+    seconds, the numpy engine's lockstep step count, the timeline slots
+    its steps read (``window_slots``) against T' a step (``slots``),
+    and (compiled) device-dispatch counts.
 
     >>> import numpy as np
     >>> from repro.core import place_many, two_phase
@@ -486,6 +514,8 @@ def place_many(problems, mappings, fit: str = "first",
         telemetry["waves"] = len(wave_s)
         telemetry["wave_s"] = wave_s
         telemetry["steps"] = eng.steps
+        telemetry["window_slots"] = eng.window_slots
+        telemetry["slots"] = eng.slots
 
     out = []
     for b, t in enumerate(batch.problems):

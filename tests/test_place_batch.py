@@ -238,6 +238,87 @@ class TestPlaceManyFixtures:
                 _assert_equal_solutions(got, want)
 
 
+def _window_grid():
+    """Two instances on an 80-slot timeline whose narrow tasks (1-4
+    slots) live at opposite ends in each node-type phase: instance 0
+    maps its early tasks to the cheap type 0 and instance 1 its late
+    ones, so every step's window is the union of two far-apart spans
+    and, once instance 0's shorter list runs out, one narrow span,
+    strictly inside T'.  Many overlapping tasks buy nodes in the middle
+    of those windows, and later tasks score them outside."""
+    from repro.core import NodeTypes, Problem
+
+    rng = np.random.default_rng(5)
+    T = 80
+    nt = NodeTypes(cap=np.array([[1.0, 1.0], [1.2, 0.8]]),
+                   cost=np.array([1.0, 1.6]))
+    problems, maps = [], []
+    for early in (True, False):
+        start = np.sort(np.concatenate([np.arange(T),
+                                        rng.integers(0, T, 40)]))
+        end = np.minimum(start + rng.integers(0, 4, len(start)), T - 1)
+        problems.append(Problem(dem=rng.uniform(0.1, 0.6, (len(start), 2)),
+                                start=start, end=end, node_types=nt, T=T))
+        cut = 25 if early else 50
+        maps.append(np.where((start < cut) == early, 0, 1).astype(np.int64))
+    return problems, maps
+
+
+class TestStepWindow:
+    @pytest.mark.parametrize("fit,filling", ALL_COMBOS)
+    def test_windowed_steps_place_like_the_loop(self, fit, filling):
+        problems, maps = _window_grid()
+        batch = pack_problems(problems)
+        assert batch.Tp >= 64
+        tel = {}
+        sols = place_many(batch, maps, fit=fit, filling=filling,
+                          telemetry=tel)
+        assert tel["window_slots"] < tel["slots"]
+        for t, mp, got in zip(batch.problems, maps, sols):
+            want = two_phase(t, mp, fit=fit, filling=filling)
+            _assert_equal_solutions(got, want)
+            assert got.cost(t) == want.cost(t)
+            assert len(got.node_type) > 2  # nodes bought mid-phase
+
+    @pytest.mark.parametrize("fit,filling", ALL_COMBOS)
+    def test_window_counts(self, fit, filling):
+        problems, maps = _window_grid()
+        batch = pack_problems(problems)
+        tel = {}
+        place_many(batch, maps, fit=fit, filling=filling, telemetry=tel)
+        assert 0 < tel["window_slots"] <= tel["slots"]
+        assert tel["slots"] == tel["steps"] * batch.Tp
+
+    def test_whole_timeline_tasks_read_every_slot(self):
+        # the batch is packed as given: every task spans all 12 slots
+        from repro.core import NodeTypes, Problem
+
+        t = Problem(dem=RNG.uniform(0.1, 0.5, (9, 2)),
+                    start=np.zeros(9, np.int64),
+                    end=np.full(9, 11), T=12,
+                    node_types=NodeTypes(cap=np.ones((2, 2)),
+                                         cost=np.array([1.0, 2.0])))
+        batch = pack_problems([t], assume_trimmed=True)
+        tel = {}
+        place_many(batch, [np.arange(9) % 2], fit="similarity",
+                   filling=True, telemetry=tel)
+        assert tel["window_slots"] == tel["slots"] == tel["steps"] * 12
+
+    def test_single_slot_tasks_read_one_slot_a_step(self):
+        from repro.core import NodeTypes, Problem
+
+        t = Problem(dem=RNG.uniform(0.1, 0.9, (15, 1)),
+                    start=np.arange(15), end=np.arange(15), T=15,
+                    node_types=NodeTypes(cap=np.ones((3, 1)),
+                                         cost=np.array([1.0, 2.0, 3.0])))
+        tel = {}
+        place_many([t], [np.arange(15) % 3], fit="first", filling=True,
+                   telemetry=tel)
+        assert tel["steps"] >= 15  # own-pack and cross-fill attempts
+        assert tel["window_slots"] == tel["steps"]
+        assert tel["slots"] == 15 * tel["steps"]
+
+
 class TestFitScoresManyKernel:
     """Oracle sweep for the batch-dim-aware Pallas fit kernel, mirroring
     the congestion_many_pallas tests (interpret-mode CPU execution)."""

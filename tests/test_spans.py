@@ -113,6 +113,10 @@ class TestEvaluateOnTheTimeline:
         wave_s = sum(s[3]["wave_s"] for s in by["repro.place.pass"])
         assert steps == t["placement"]["steps"] > 0
         assert wave_s == pytest.approx(t["placement"]["wave_s_total"])
+        for key in ("window_slots", "slots"):
+            total = sum(s[3][key] for s in by["repro.place.pass"])
+            assert total == t["placement"][key] > 0
+        assert t["placement"]["window_slots"] <= t["placement"]["slots"]
 
     def test_span_count_does_not_grow_with_n(self, tmp_path):
         _, small = self._evaluate(tmp_path / "small", 12)
