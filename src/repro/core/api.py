@@ -256,7 +256,7 @@ def evaluate_many(problems, algos=ALGORITHMS, backend=_UNSET,
     >>> entries = evaluate_many(grid, algos=("penalty-map",),
     ...                         lp_iters=30)
     >>> sorted(entries[0])
-    ['costs', 'lb', 'normalized', 'wall_s']
+    ['costs', 'lb', 'normalized', 'plan', 'wall_s']
     >>> list(entries[1]["costs"])
     ['penalty-map']
     """

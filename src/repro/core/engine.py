@@ -484,8 +484,11 @@ class FleetResult:
 
     entries: one §VI protocol dict per instance, in submission order —
         {'lb', 'costs': {algo: cost}, 'normalized': {algo: cost/lb},
-        'wall_s': {algo: s}} plus a 'solver' telemetry block in tol
-        mode (iters/restarts/kkt/converged per instance).
+        'wall_s': {algo: s}, 'plan': {algo: Solution}} plus a 'solver'
+        telemetry block in tol mode (iters/restarts/kkt/converged per
+        instance); 'plan' holds each algorithm's kept plan, verified
+        when ``PlacementConfig.check`` is set (the batched and compiled
+        engines).
     stats: the ``SolveStats`` of each batched LP dispatch (one per
         bucket shard, or one per warm-started group); empty in legacy
         fixed-iters mode.
@@ -563,12 +566,13 @@ def _protocol_batched(batch: ProblemBatch, lp_results, algos, fits,
     spent verifying kept plans are added to ``timings["verify_s"]``.
     Each pass is a ``repro.place.pass`` span carrying its lockstep step
     count, wave seconds and timeline slots read (windowed and not) as
-    trace metadata."""
+    trace metadata.  Each entry keeps every algorithm's chosen plan,
+    verified when ``check`` is set, under ``"plan"``."""
     from .api import rightsize
 
     B = batch.B
     out = [{"lb": res.lower_bound, "costs": {}, "normalized": {},
-            "wall_s": {}} for res in lp_results]
+            "wall_s": {}, "plan": {}} for res in lp_results]
     for algo in algos:
         t0 = time.perf_counter()
         filling = algo.endswith("-f")
@@ -584,6 +588,7 @@ def _protocol_batched(batch: ProblemBatch, lp_results, algos, fits,
                                 lp_result=lp_results[b], check=check)
                 out[b]["costs"][algo] = sol.cost(t)
                 out[b]["wall_s"][algo] = sol.meta["wall_s"]
+                out[b]["plan"][algo] = sol
             continue
         best: list[Solution | None] = [None] * B
         best_cost = [float("inf")] * B
@@ -613,6 +618,7 @@ def _protocol_batched(batch: ProblemBatch, lp_results, algos, fits,
                     verify(t, best[b])
             out[b]["costs"][algo] = best_cost[b]
             out[b]["wall_s"][algo] = wall
+            out[b]["plan"][algo] = best[b]
     for entry in out:
         lb = max(entry["lb"], 1e-12)
         entry["normalized"] = {a: c / lb

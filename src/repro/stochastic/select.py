@@ -44,15 +44,16 @@ from __future__ import annotations
 import dataclasses
 import json
 import math
-import time
 
 import numpy as np
 
-from repro.core import (FleetEngine, SolverConfig, pack_problems,
-                        trim_timeline)
+from repro.core import FleetEngine, SolverConfig, Solution, pack_problems
 from repro.core.batch import dispatch_count
+from repro.core.engine import (_ENGINE_STEPPER, _placement_telemetry,
+                               _protocol_batched)
 from repro.core.lp_pdhg import SolveStats
 from repro.core.placement import FIT_POLICIES
+from repro.core.spans import span
 
 from .forecast import DemandForecast
 from .scenarios import ScenarioSet, fan_out
@@ -215,6 +216,13 @@ class StochasticResult:
     lp_dispatches / buckets: how many compiled LP dispatches the K
         scenarios cost (== 1 without sharding) and the bucket count
         (== 1 by the shared-shape construction).
+    timings: the ``repro.*`` span seconds (``total_s``, ``fanout_s``,
+        ``lp_s``, ``place_s``, ``verify_s``, ``select_s``) and the
+        ``placement`` block of ``FleetResult.timings``.
+    scenario_solutions: (K,) each scenario's kept plan (in trimmed
+        coordinates), verified under the engine's
+        ``PlacementConfig.check``.
+    scenario_lbs: (K,) each scenario's certified LP lower bound.
     """
 
     config: StochasticConfig
@@ -232,6 +240,8 @@ class StochasticResult:
     lp_dispatches: int
     buckets: int
     timings: dict
+    scenario_solutions: list[Solution]
+    scenario_lbs: np.ndarray
 
     @property
     def K(self) -> int:
@@ -321,7 +331,8 @@ def plan_stochastic(forecast: DemandForecast | ScenarioSet,
                     engine: FleetEngine | None = None,
                     current_fleet: np.ndarray | None = None,
                     ) -> StochasticResult:
-    """Forecast -> fan-out -> ONE batched solve -> CVaR selection.
+    """Forecast -> fan-out -> ONE batched solve -> placement -> CVaR
+    selection.
 
     ``forecast`` may be a ``DemandForecast`` (fanned out here with
     ``config.scenarios``/``config.seed``) or a pre-built
@@ -330,76 +341,80 @@ def plan_stochastic(forecast: DemandForecast | ScenarioSet,
     must not configure warm-started sweeps (``solve_scenarios``
     rejects that).  ``current_fleet`` activates the Eva-style
     reconfiguration term of ``config.recfg_weight``.
+
+    Each scenario is placed by the same protocol as
+    ``FleetEngine.evaluate`` (every mapping and fit policy of
+    ``config.algo``, the cheapest plan kept and verified under
+    ``engine.placement.check``).  Every phase is a ``repro.*`` span
+    inside ``repro.robust`` whose seconds go to ``timings``.
     """
-    scenario_set = forecast if isinstance(forecast, ScenarioSet) \
-        else fan_out(forecast, config.scenarios, config.seed)
-    problems = list(scenario_set.problems)
-    base = scenario_set.forecast.base
-    node_cost = base.node_types.cost
     if engine is None:
         engine = FleetEngine(solver=SolverConfig(tol=5e-3, iters=4000),
                              algos=(config.algo,))
+    timings: dict = {"verify_s": 0.0}
+    tels: list[dict] = []
+    with span("robust", timings, "total_s"):
+        with span("fanout", timings, "fanout_s"):
+            scenario_set = forecast if isinstance(forecast, ScenarioSet) \
+                else fan_out(forecast, config.scenarios, config.seed)
+            base = scenario_set.forecast.base
+            batch = pack_problems(engine._trimmed(scenario_set.problems),
+                                  assume_trimmed=True)
 
-    t0 = time.perf_counter()
-    d0 = dispatch_count()
-    lp_results, stats = engine.solve_scenarios(problems)
-    lp_dispatches = dispatch_count() - d0
-    lp_s = time.perf_counter() - t0
+        with span("lp", timings, "lp_s"):
+            d0 = dispatch_count()
+            lp_results, stats = engine.solve_scenarios(batch)
+            lp_dispatches = dispatch_count() - d0
 
-    # one lockstep placement pass per fit policy over the shared-shape
-    # batch; each scenario keeps its own cheapest feasible fleet
-    t0 = time.perf_counter()
-    filling = config.algo.endswith("-f")
-    trimmed = [trim_timeline(p)[0] for p in problems]
-    if config.algo.startswith("penalty-map"):
-        from repro.core import penalty_map
+        # one lockstep pass per mapping and fit policy over the
+        # shared-shape batch; each scenario keeps its cheapest plan
+        with span("place", timings, "place_s"):
+            entries = _protocol_batched(
+                batch, lp_results, (config.algo,), FIT_POLICIES,
+                engine.placement.backend, check=engine.placement.check,
+                stepper=_ENGINE_STEPPER.get(engine.placement.engine,
+                                            "lockstep"),
+                tels=tels, timings=timings)
+        solutions = [e["plan"][config.algo] for e in entries]
+        scenario_costs = np.array([e["costs"][config.algo]
+                                   for e in entries])
+        plans = np.stack([sol.nodes_per_type(t) for sol, t
+                          in zip(solutions, batch.problems)]).astype(np.int64)
 
-        mapsets = [[penalty_map(t, kind) for t in trimmed]
-                   for kind in ("avg", "max")]
-    else:
-        mapsets = [[r.mapping for r in lp_results]]
-    batch = pack_problems(trimmed, assume_trimmed=True)
-    K, m = len(problems), base.m
-    best_cost = np.full(K, np.inf)
-    plans = np.zeros((K, m), dtype=np.int64)
-    for maps in mapsets:
-        for fit in FIT_POLICIES:
-            sols = engine.place(batch, maps, fit=fit, filling=filling)
-            for s, (t, sol) in enumerate(zip(batch.problems, sols)):
-                c = sol.cost(t)
-                if c < best_cost[s]:
-                    best_cost[s] = c
-                    plans[s] = sol.nodes_per_type(t)
-    place_s = time.perf_counter() - t0
+        with span("select", timings, "select_s") as ann:
+            node_cost = base.node_types.cost
+            fleets = candidate_fleets(plans, quantiles=config.quantiles,
+                                      current=current_fleet)
+            ov = overload_costs(plans, fleets, node_cost)
+            fleet_costs = (fleets * node_cost[None, :]).sum(axis=1)
 
-    fleets = candidate_fleets(plans, quantiles=config.quantiles,
-                              current=current_fleet)
-    ov = overload_costs(plans, fleets, node_cost)
-    fleet_costs = (fleets * node_cost[None, :]).sum(axis=1)
+            def _row(alpha: float, lam: float, j: int) -> dict:
+                r6 = lambda v: round(float(v), 6)  # noqa: E731
+                return {
+                    "alpha": alpha, "lambda": lam,
+                    "fleet": fleets[j].tolist(),
+                    "fleet_cost": r6(fleet_costs[j]),
+                    "mean_overload": r6(ov[:, j].mean()),
+                    "cvar_overload": r6(cvar(ov[:, j], alpha)),
+                    "worst_overload": r6(ov[:, j].max()),
+                }
 
-    def _row(alpha: float, lam: float, j: int) -> dict:
-        r6 = lambda v: round(float(v), 6)  # noqa: E731
-        return {
-            "alpha": alpha, "lambda": lam,
-            "fleet": fleets[j].tolist(),
-            "fleet_cost": r6(fleet_costs[j]),
-            "mean_overload": r6(ov[:, j].mean()),
-            "cvar_overload": r6(cvar(ov[:, j], alpha)),
-            "worst_overload": r6(ov[:, j].max()),
-        }
-
-    sel = dict(alpha=config.cvar_alpha, lam=config.cvar_lambda,
-               premium=config.overload_premium,
-               recfg_weight=config.recfg_weight, current=current_fleet)
-    j_exp = _select(fleets, ov, node_cost, **{**sel, "lam": 0.0})
-    frontier = [_row(config.cvar_alpha, 0.0, j_exp)]
-    alphas = sorted(set(config.frontier_alphas) | {config.cvar_alpha})
-    j_sel = j_exp
-    for alpha in alphas:
-        j = _select(fleets, ov, node_cost, **{**sel, "alpha": alpha})
-        frontier.append(_row(alpha, config.cvar_lambda, j))
-        if alpha == config.cvar_alpha:
-            j_sel = j
+            sel = dict(alpha=config.cvar_alpha, lam=config.cvar_lambda,
+                       premium=config.overload_premium,
+                       recfg_weight=config.recfg_weight,
+                       current=current_fleet)
+            j_exp = _select(fleets, ov, node_cost, **{**sel, "lam": 0.0})
+            frontier = [_row(config.cvar_alpha, 0.0, j_exp)]
+            alphas = sorted(set(config.frontier_alphas) | {config.cvar_alpha})
+            j_sel = j_exp
+            for alpha in alphas:
+                j = _select(fleets, ov, node_cost, **{**sel, "alpha": alpha})
+                frontier.append(_row(alpha, config.cvar_lambda, j))
+                if alpha == config.cvar_alpha:
+                    j_sel = j
+            ann.set_metadata(scenarios=len(plans), candidates=len(fleets))
+    timings["placement"] = _placement_telemetry(engine.placement.engine,
+                                                tels)
 
     return StochasticResult(
         config=config,
@@ -407,15 +422,16 @@ def plan_stochastic(forecast: DemandForecast | ScenarioSet,
         fleet_cost=float(fleet_costs[j_sel]),
         expected_fleet=fleets[j_exp],
         expected_fleet_cost=float(fleet_costs[j_exp]),
-        scenario_costs=best_cost,
+        scenario_costs=scenario_costs,
         scenario_plans=plans,
         overload=ov[:, j_sel],
         expected_overload=ov[:, j_exp],
-        max_fleet_cost=float(
-            (plans.max(axis=0) * node_cost).sum()),
+        max_fleet_cost=float((plans.max(axis=0) * node_cost).sum()),
         frontier=frontier,
         stats=list(stats),
         lp_dispatches=int(lp_dispatches),
         buckets=1,
-        timings={"lp_s": lp_s, "place_s": place_s},
+        timings=timings,
+        scenario_solutions=solutions,
+        scenario_lbs=np.array([e["lb"] for e in entries]),
     )

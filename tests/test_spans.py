@@ -124,6 +124,47 @@ class TestEvaluateOnTheTimeline:
         assert sorted(s[0] for s in small) == sorted(s[0] for s in large)
 
 
+class TestRobustPlanOnTheTimeline:
+    def test_spans_nest_inside_the_robust_span(self, tmp_path):
+        from repro.stochastic import (StochasticConfig, gct_forecast,
+                                      plan_stochastic)
+
+        fc = gct_forecast(n=40, m=4, seed=3, burst_prob=0.1)
+        config = StochasticConfig(scenarios=6, quantiles=3)
+        plan_stochastic(fc, config)  # compile outside the trace
+        with jax.profiler.trace(str(tmp_path)):
+            res = plan_stochastic(fc, config)
+        by = {}
+        for s in _host_spans(tmp_path):
+            by.setdefault(s[0], []).append(s)
+        (robust,), (place,) = by["repro.robust"], by["repro.place"]
+        for name in ("repro.fanout", "repro.lp", "repro.place",
+                     "repro.select"):
+            (inner,) = by[name]
+            assert _inside(inner, robust)
+        # one pass per fit policy; one verify per scenario's kept plan
+        assert len(by["repro.place.pass"]) == 2
+        assert len(by["repro.verify"]) == config.scenarios
+        for name in ("repro.place.pass", "repro.verify"):
+            assert all(_inside(s, place) for s in by[name])
+        (select,) = by["repro.select"]
+        assert select[3]["scenarios"] == config.scenarios
+        assert select[3]["candidates"] >= 1
+        t = res.timings
+        for key in ("total_s", "fanout_s", "lp_s", "place_s", "verify_s",
+                    "select_s"):
+            assert t[key] > 0, key
+        assert (t["fanout_s"] + t["lp_s"] + t["place_s"] + t["select_s"]
+                <= t["total_s"])
+        assert t["verify_s"] <= t["place_s"]
+        passes = by["repro.place.pass"]
+        assert t["placement"]["calls"] == len(passes)
+        for key in ("steps", "window_slots", "slots"):
+            assert sum(s[3][key] for s in passes) == t["placement"][key] > 0
+        assert sum(s[3]["wave_s"] for s in passes) == pytest.approx(
+            t["placement"]["wave_s_total"])
+
+
 class TestStepCounter:
     def test_steps_are_the_lockstep_iterations(self):
         # instance 0: three tasks on type 0; instance 1: two on each

@@ -247,6 +247,46 @@ def test_plan_stochastic_one_dispatch_one_bucket():
     assert s["fleet_cost"] <= s["max_fleet_cost"] + 1e-9
 
 
+def test_plan_stochastic_keeps_each_scenarios_verified_protocol_plan(
+        monkeypatch):
+    from repro.core import FIT_POLICIES, trim_timeline, two_phase, verify
+    from repro.core import engine as engine_mod
+
+    fc = gct_forecast(n=40, m=4, seed=3, burst_prob=0.1)
+    config = StochasticConfig(scenarios=6, quantiles=3)
+    engine = FleetEngine(solver=SolverConfig(tol=5e-3, iters=4000),
+                         algos=(config.algo,))
+    verified = []
+
+    def recording_verify(problem, solution, *args, **kwargs):
+        verified.append(solution)
+        return verify(problem, solution, *args, **kwargs)
+
+    monkeypatch.setattr(engine_mod, "verify", recording_verify)
+    res = plan_stochastic(fc, config, engine=engine)
+    # every kept plan, and nothing else, went through verify
+    assert [id(s) for s in verified] == [id(s) for s in
+                                         res.scenario_solutions]
+
+    problems = fan_out(fc, config.scenarios, config.seed).problems
+    lp, _ = engine.solve_scenarios(list(problems))
+    np.testing.assert_array_equal(res.scenario_lbs,
+                                  [r.lower_bound for r in lp])
+    for s, p in enumerate(problems):
+        t = trim_timeline(p)[0]
+        sols = [two_phase(t, lp[s].mapping, fit=fit, filling=True)
+                for fit in FIT_POLICIES]
+        costs = [sol.cost(t) for sol in sols]
+        want = sols[int(np.argmin(costs))]  # the first of equal costs
+        kept = res.scenario_solutions[s]
+        assert res.scenario_costs[s] == min(costs) == kept.cost(t)
+        np.testing.assert_array_equal(kept.node_type, want.node_type)
+        np.testing.assert_array_equal(kept.assign, want.assign)
+        np.testing.assert_array_equal(res.scenario_plans[s],
+                                      want.nodes_per_type(t))
+        verify(t, kept)
+
+
 def test_solve_scenarios_rejects_ragged_shapes():
     a = synthetic_instance(SyntheticSpec(n=6, m=2, D=2, T=8, seed=0))
     b = synthetic_instance(SyntheticSpec(n=7, m=2, D=2, T=8, seed=0))
