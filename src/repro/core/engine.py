@@ -565,7 +565,8 @@ def _protocol_batched(batch: ProblemBatch, lp_results, algos, fits,
     per-call stepper telemetry is appended to ``tels``, and the seconds
     spent verifying kept plans are added to ``timings["verify_s"]``.
     Each pass is a ``repro.place.pass`` span carrying its lockstep step
-    count, wave seconds and timeline slots read (windowed and not) as
+    count, wave seconds, timeline slots read (windowed and not) and
+    cross-fill attempts (all, and those skipped without a step) as
     trace metadata.  Each entry keeps every algorithm's chosen plan,
     verified when ``check`` is set, under ``"plan"``."""
     from .api import rightsize
@@ -604,7 +605,9 @@ def _protocol_batched(batch: ProblemBatch, lp_results, algos, fits,
                         steps=tel.get("steps", 0),
                         wave_s=sum(tel.get("wave_s", ())),
                         window_slots=tel.get("window_slots", 0),
-                        slots=tel.get("slots", 0))
+                        slots=tel.get("slots", 0),
+                        fill_attempts=tel.get("fill_attempts", 0),
+                        fill_skipped=tel.get("fill_skipped", 0))
                 if tels is not None:
                     tels.append(tel)
                 for b, (t, s) in enumerate(zip(batch.problems, sols)):
@@ -631,14 +634,16 @@ def _placement_telemetry(engine: str, tels: list) -> dict:
     timings block: which stepper actually ran, how many device
     dispatches the compiled stepper issued, how often it fell back,
     the summed per-phase (wave) seconds, and the numpy lockstep
-    engine's step count and timeline slots read (``window_slots``, of
-    ``slots``)."""
+    engine's step count, timeline slots read (``window_slots``, of
+    ``slots``) and cross-fill attempts (``fill_attempts``, of them
+    ``fill_skipped`` without a step)."""
     out: dict = {"engine": engine, "calls": len(tels)}
     if engine == "loop" or not tels:
         return out
     out["waves"] = max((t.get("waves", 0) for t in tels), default=0)
     out["wave_s_total"] = sum(sum(t.get("wave_s", ())) for t in tels)
-    for key in ("steps", "window_slots", "slots"):
+    for key in ("steps", "window_slots", "slots", "fill_attempts",
+                "fill_skipped"):
         out[key] = sum(t.get(key, 0) for t in tels)
     if engine == "compiled":
         out["dispatches"] = sum(t.get("dispatches", 0) for t in tels)

@@ -15,7 +15,11 @@ of B Python-level ``TypePool.find_fit`` calls.  All per-step bookkeeping
 instances, so a step costs O(1) numpy dispatches regardless of B.
 Each step reads and writes only its window of the time axis, the live
 tasks' span union ``[t0, t1)``, not all T' slots: a task's span is
-typically a small part of the trimmed timeline.
+typically a small part of the trimmed timeline.  A cross-fill sub-phase
+(numpy backend) takes no step for an attempt that fits no node: it
+tests each instance's pending candidates against a range-minimum table
+of its pool and places the first that fits, so each of its steps places
+a task in every live instance.
 
 Wave synchronization is the engine's load-bearing trick: instances are
 independent, so inserting barriers between their (own-pack, cross-fill)
@@ -59,11 +63,21 @@ properties make that hold:
     quantize scores to 9 decimals before the argmax — reassociation
     noise collapses onto identical values and the first-max tie-break
     picks the same node on every path.
+  * skipping cross-fill misses changes no placement.  A cross-fill
+    sub-phase buys no node, so capacity only shrinks in it, and a miss
+    mutates nothing: the candidates an instance tries before its next
+    fit meet the same pool at their own turn in ``two_phase`` as they
+    do when tested together.  The test itself is ``min over the span
+    of rem >= dem - EPS`` per dimension, the same float comparison as
+    ``find_fit``'s ``all(rem >= dem - EPS)``: a minimum is exact, and
+    the table's entries are the pool's own values, updated by the same
+    ``rem - dem`` subtraction.
 
 ``backend='kernel'`` routes the scoring pass through the batch-dim-aware
 Pallas kernel ``fit_scores_many`` (grid over B; fp32, matching the
-single-instance kernel backend), ``backend='numpy'`` uses the bit-exact
-vectorized host path.
+single-instance kernel backend; cross-fill then tries every attempt in a
+step of its own), ``backend='numpy'`` uses the bit-exact vectorized host
+path.
 """
 
 from __future__ import annotations
@@ -159,6 +173,77 @@ def _batch_aux(batch: ProblemBatch, phases: list[_Phases]):
     return dn, capx, span_all
 
 
+class _RangeMin:
+    """Sparse table of range minima over the time axis of N nodes'
+    remaining capacity ``(N, T, D)``, kept current while they lose
+    capacity.
+
+    ``m[i, k, t]`` is the per-dimension minimum of ``nodes[i, t : t +
+    2**k]`` for ``t <= T - 2**k`` (the rest of a level is never read);
+    level 0 is the capacity itself.  A span's minimum is the minimum of
+    two entries of one level (``keys`` finds them, ``min`` reads them);
+    ``take`` subtracts a demand from one node over a span and
+    recomputes, level by level, only the entries whose ranges meet the
+    span.  The minimum is exact, so comparing it with a threshold is the
+    same comparison as with every slot of the span.  The table lives in
+    ``buf`` (grown when too small), so a caller that builds many tables
+    can reuse one buffer.
+    """
+
+    def __init__(self, nodes: np.ndarray, buf: np.ndarray | None = None):
+        N, T, D = nodes.shape
+        L = T.bit_length()  # levels 0 .. floor(log2 T)
+        size = N * L * T * D
+        if buf is None or buf.size < size:
+            buf = np.empty(size)
+        self.buf = buf
+        self.m = m = buf[:size].reshape(N, L, T, D)
+        m[:, 0] = nodes
+        for k in range(1, L):
+            h, n = 1 << (k - 1), T - (1 << k) + 1
+            np.minimum(m[:, k - 1, :n], m[:, k - 1, h: h + n],
+                       out=m[:, k, :n])
+        # floor(log2(span length)), indexed by length - 1; frexp is exact
+        self.lg = np.frexp(np.arange(1, T + 1))[1] - 1
+        self.slots = m.reshape(-1, D)  # one row per (node, level, t)
+        self.T, self.D, self.node_rows = T, D, L * T
+        # take's per-level bounds, in flat units of a node's level:
+        # (half width, one past the last valid start, width - 1)
+        self.bounds = [((1 << (k - 1)) * D, (T - (1 << k) + 1) * D,
+                        ((1 << k) - 1) * D) for k in range(1, L)]
+
+    @property
+    def nodes(self) -> np.ndarray:
+        return self.m[:, 0]
+
+    def keys(self, s, e):
+        """Rows, within a node's block of ``slots``, of the two entries
+        whose minimum is the minimum over ``[s, e]``."""
+        k = self.lg[e - s]
+        return k * self.T + s, k * self.T + e - (1 << k) + 1
+
+    def min(self, node, lo, hi) -> np.ndarray:
+        """Minimum of node ``node`` over the span whose ``keys`` are
+        ``lo``, ``hi``: the three broadcast to a shape S; returns
+        S + (D,)."""
+        at = node * self.node_rows
+        return np.minimum(self.slots.take(at + lo, axis=0),
+                          self.slots.take(at + hi, axis=0))
+
+    def take(self, node: int, s: int, e: int, dem) -> None:
+        """Subtract ``dem`` (D,) from node ``node`` over ``[s, e]`` and
+        bring every level up to date."""
+        levels = self.m[node]  # (L, T, D)
+        levels[0, s: e + 1] -= dem
+        flat = levels.reshape(len(levels), -1)
+        lo, hi = s * self.D, (e + 1) * self.D
+        prev = flat[0]
+        for cur, (h, stop, back) in zip(flat[1:], self.bounds):
+            a, z = max(lo - back, 0), min(hi, stop)
+            np.minimum(prev[a:z], prev[a + h: z + h], out=cur[a:z])
+            prev = cur
+
+
 class _Engine:
     """Shared lockstep state across the waves of one place_many call."""
 
@@ -181,6 +266,10 @@ class _Engine:
         # read without windows (T' each)
         self.window_slots = 0
         self.slots = 0
+        # cross-fill attempts, and those of them skipped without a step
+        self.fill_attempts = 0
+        self.fill_skipped = 0
+        self._table_buf = None  # _RangeMin storage, reused across waves
 
     def run_wave(self, k: int, fit: str, filling: bool) -> bool:
         """Own-pack + cross-fill sub-phases of every instance's k-th
@@ -207,9 +296,12 @@ class _Engine:
         pool = self._pool
         if filling:
             fill = [self._live(b, self.phases[b].fill[k]) for b in wave]
-            self._run_sub(wave, tau, pool, w, fill, purchase=False,
-                          similarity=False)
-            pool = self._pool
+            if self.backend == "numpy":
+                self._run_fill(wave, pool, w, fill)
+            else:
+                self._run_sub(wave, tau, pool, w, fill, purchase=False,
+                              similarity=False)
+                pool = self._pool
         # scatter the finished type-block back into the master array
         hi = int((lo + w).max())
         while hi > self.n_cap:
@@ -239,7 +331,8 @@ class _Engine:
         pool tensor as their instance leaves, and the working set is
         compacted to the live rows once enough have finished, so the
         batched ops stay sized to the instances that still have
-        attempts.  Fill-only sub-phases drop node-less instances up
+        attempts.  Fill-only sub-phases (the kernel backend's; the
+        numpy backend's run in ``_run_fill``) drop node-less instances up
         front: with an empty pool every attempt is a guaranteed miss
         that mutates nothing, exactly as ``find_fit`` returns None on an
         empty TypePool.  All per-task data (demands, spans, norms,
@@ -433,9 +526,109 @@ class _Engine:
                 self.assign[b_sel, u_sel] = \
                     self.counts[b_sel] - wl[place_a] + j_all
                 self.placed[b_sel, u_sel] = True
+            if not purchase:
+                self.fill_attempts += int(alive.sum())
             ptr += alive
             self.steps += 1
         self._pool = pool
+
+    def _run_fill(self, wave, pool, w, lists):
+        """Lockstep one cross-fill sub-phase (first fit, no purchase) on
+        the numpy backend, in place on the wave's pool tensor.
+
+        No node is bought here, so a row's pool only loses capacity, and
+        only where the row places a task.  Each step takes every live
+        row's pending candidates in list order, tests them against the
+        row's current nodes through a range-minimum table kept current
+        after every placement (``_RangeMin``), and places the first that
+        fits on its lowest fitting node.  The candidates before it fit
+        no node now, and fitted none at their own turn in ``two_phase``
+        either, since a miss changes nothing: they are skipped without a
+        step.  So every step places one task in each row that still has
+        one to place.
+        """
+        batch = self.batch
+        keep = np.flatnonzero(
+            (w > 0) & (np.array([len(x) for x in lists]) > 0))
+        if len(keep) == 0:
+            return
+        A, BLOCK = len(keep), 1024  # the largest block a row tests
+        lens = np.array([len(lists[a]) for a in keep])
+        b_l, wl = wave[keep], w[keep]
+        # the table holds each row's wl nodes in turn from first[row],
+        # then one node with no capacity anywhere, which fits nothing
+        # and pads every row's nodes to W
+        first = np.cumsum(wl) - wl
+        row_of = np.repeat(keep, wl)
+        j_of = np.arange(len(row_of)) - np.repeat(first, wl)
+        nodes = np.concatenate(
+            [pool[row_of, j_of], np.full((1,) + pool.shape[2:], -np.inf)])
+        table = _RangeMin(nodes, self._table_buf)
+        self._table_buf = table.buf
+        W = int(wl.max())
+        node_at = np.where(np.arange(W) < wl[:, None],
+                           first[:, None] + np.arange(W), len(row_of))
+        # per-candidate data, padded past each list's end with BLOCK
+        # candidates whose threshold no capacity meets
+        u_pad = np.zeros((A, int(lens.max()) + BLOCK), np.int64)
+        for r, a in enumerate(keep):
+            u_pad[r, : lens[r]] = lists[a]
+        bb = b_l[:, None]
+        s_pad = batch.start[bb, u_pad].astype(np.int64)
+        e_pad = batch.end[bb, u_pad].astype(np.int64)
+        real = np.arange(u_pad.shape[1]) < lens[:, None]
+        thr = np.where(real[..., None], batch.dem[bb, u_pad] - EPS, np.inf)
+        lo_pad, hi_pad = table.keys(s_pad, e_pad)
+        ptr = np.zeros(A, np.int64)
+        hits = 0
+        while True:
+            rows = np.flatnonzero(ptr < lens)
+            if len(rows) == 0:
+                break
+            # each live row's first candidate that fits, from its
+            # pointer on, in blocks that double while a row finds none
+            got_r, got_j, block = [], [], 8
+            while len(rows):
+                rr = rows[:, None]
+                idx = ptr[rr] + np.arange(block)               # (P, K)
+                # all(rem >= dem - EPS) over the span, as find_fit
+                # compares it, through the span's exact minimum
+                lowest = table.min(node_at[rr], lo_pad[rr, idx][..., None],
+                                   hi_pad[rr, idx][..., None])
+                fit = (lowest >= thr[rr, idx][:, :, None]).all(axis=3)
+                any_node = fit.any(axis=2)                     # (P, K)
+                has = any_node.any(axis=1)
+                hit = any_node.argmax(axis=1)
+                ptr[rows] = np.where(has, ptr[rows] + hit,
+                                     np.minimum(ptr[rows] + block,
+                                                lens[rows]))
+                got_r.append(rows[has])
+                got_j.append(fit[has, hit[has]].argmax(axis=1))
+                rows = rows[~has]
+                rows = rows[ptr[rows] < lens[rows]]
+                block = min(2 * block, BLOCK)
+            r = np.concatenate(got_r)
+            if len(r) == 0:  # every pending candidate missed
+                continue
+            j = np.concatenate(got_j)
+            at = ptr[r]
+            s, e = s_pad[r, at], e_pad[r, at]
+            u, b_sel = u_pad[r, at], b_l[r]
+            for args in zip(node_at[r, j].tolist(), s.tolist(),
+                            e.tolist(), batch.dem[b_sel, u]):
+                table.take(*args)
+            # global node id = block start + pool-local index
+            self.assign[b_sel, u] = self.counts[b_sel] - wl[r] + j
+            self.placed[b_sel, u] = True
+            ptr[r] += 1
+            hits += len(r)
+            self.steps += 1
+            self.window_slots += int(e.max()) + 1 - int(s.min())
+            self.slots += batch.Tp
+        pool[row_of, j_of] = table.nodes[:-1]
+        self.fill_attempts += int(lens.sum())
+        self.fill_skipped += int(lens.sum()) - hits
+
 
 def place_many(problems, mappings, fit: str = "first",
                filling: bool = False, backend: str = "numpy",
@@ -462,7 +655,9 @@ def place_many(problems, mappings, fit: str = "first",
     in place with the stepper actually used, wave count, per-wave
     seconds, the numpy engine's lockstep step count, the timeline slots
     its steps read (``window_slots``) against T' a step (``slots``),
-    and (compiled) device-dispatch counts.
+    its cross-fill attempts (``fill_attempts``) and those skipped
+    without a step (``fill_skipped``), and (compiled) device-dispatch
+    counts.
 
     >>> import numpy as np
     >>> from repro.core import place_many, two_phase
@@ -516,6 +711,8 @@ def place_many(problems, mappings, fit: str = "first",
         telemetry["steps"] = eng.steps
         telemetry["window_slots"] = eng.window_slots
         telemetry["slots"] = eng.slots
+        telemetry["fill_attempts"] = eng.fill_attempts
+        telemetry["fill_skipped"] = eng.fill_skipped
 
     out = []
     for b, t in enumerate(batch.problems):

@@ -319,6 +319,108 @@ class TestStepWindow:
         assert tel["slots"] == 15 * tel["steps"]
 
 
+def _miss_grid(B):
+    """B instances whose cross-fill candidates mostly cannot fit: the
+    cheap-per-capacity type 0 is filled first, and most tasks mapped to
+    the larger type 1 demand more than a type-0 node holds in some
+    dimension, so they miss every node of type 0's pool; the rest fit
+    wherever capacity is left."""
+    from repro.core import NodeTypes, Problem
+
+    nt = NodeTypes(cap=np.array([[1.0, 1.0], [2.0, 2.0]]),
+                   cost=np.array([1.0, 2.5]))
+    problems, maps = [], []
+    for b in range(B):
+        rng = np.random.default_rng(100 + b)
+        n, T = 60 + 5 * b, 30
+        start = rng.integers(0, T, n)
+        end = np.minimum(start + rng.integers(0, 12, n), T - 1)
+        dem = rng.uniform(0.05, 0.3, (n, 2))
+        big = rng.random(n) < 0.4
+        dem[big, rng.integers(0, 2, int(big.sum()))] = \
+            rng.uniform(1.05, 1.9, int(big.sum()))
+        mp = np.where(big | (rng.random(n) < 0.4), 1, 0).astype(np.int64)
+        problems.append(Problem(dem=dem, start=start, end=end,
+                                node_types=nt, T=T))
+        maps.append(mp)
+    return problems, maps
+
+
+class TestCrossFillSkip:
+    @pytest.mark.parametrize("fit", FIT_POLICIES)
+    @pytest.mark.parametrize("B", [1, 2, 16])
+    def test_skipping_misses_places_like_the_loop(self, B, fit):
+        problems, maps = _miss_grid(B)
+        batch = pack_problems(problems)
+        tel = {}
+        sols = place_many(batch, maps, fit=fit, filling=True,
+                          telemetry=tel)
+        for t, mp, got in zip(batch.problems, maps, sols):
+            want = two_phase(t, mp, fit=fit, filling=True)
+            _assert_equal_solutions(got, want)
+            assert got.cost(t) == want.cost(t)
+            assert_feasible(t, got)
+        assert 0 < tel["fill_skipped"] <= tel["fill_attempts"]
+
+    def test_every_cross_fill_step_places_a_task(self):
+        # one instance: a step places one task, own-pack or cross-fill
+        problems, maps = _miss_grid(1)
+        tel = {}
+        place_many(problems, maps, fit="first", filling=True,
+                   telemetry=tel)
+        assert tel["steps"] == problems[0].n
+        assert tel["fill_skipped"] > 0
+
+    @pytest.mark.parametrize("fit", FIT_POLICIES)
+    def test_no_filling_counts_no_attempt(self, fit):
+        problems, maps = _miss_grid(2)
+        tel = {}
+        place_many(problems, maps, fit=fit, filling=False, telemetry=tel)
+        assert tel["fill_attempts"] == tel["fill_skipped"] == 0
+
+    def test_kernel_backend_tries_every_attempt(self):
+        problems, maps = _miss_grid(2)
+        tel = {}
+        place_many(problems, maps, fit="first", filling=True,
+                   backend="kernel", telemetry=tel)
+        assert tel["fill_attempts"] > 0 and tel["fill_skipped"] == 0
+
+
+class TestRangeMin:
+    @pytest.mark.parametrize("T", [1, 2, 7, 32, 45])
+    def test_queries_match_brute_force_after_updates(self, T):
+        from repro.core.place_batch import _RangeMin
+
+        rng = np.random.default_rng(T)
+        N, D = 3, 2
+        nodes = rng.uniform(0.5, 2.0, (N, T, D))
+        table = _RangeMin(nodes.copy())
+        s, e = np.triu_indices(T)  # every span [s, e]
+        lo, hi = table.keys(s, e)
+        for _ in range(25):
+            i = int(rng.integers(N))
+            a = int(rng.integers(T))
+            z = int(rng.integers(a, T))
+            dem = rng.uniform(0.0, 0.3, D)
+            table.take(i, a, z, dem)
+            nodes[i, a: z + 1] -= dem
+            np.testing.assert_array_equal(table.nodes, nodes)
+            for j in range(N):
+                want = np.array([nodes[j, x: y + 1].min(axis=0)
+                                 for x, y in zip(s, e)])
+                np.testing.assert_array_equal(table.min(j, lo, hi), want)
+
+    def test_reuses_a_large_enough_buffer(self):
+        from repro.core.place_batch import _RangeMin
+
+        rng = np.random.default_rng(0)
+        big = _RangeMin(rng.uniform(size=(4, 20, 2)))
+        small = _RangeMin(rng.uniform(size=(2, 9, 2)), big.buf)
+        assert small.buf is big.buf
+        assert _RangeMin(rng.uniform(size=(5, 20, 2)), big.buf).buf \
+            is not big.buf
+
+
 class TestFitScoresManyKernel:
     """Oracle sweep for the batch-dim-aware Pallas fit kernel, mirroring
     the congestion_many_pallas tests (interpret-mode CPU execution)."""

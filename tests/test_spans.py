@@ -118,6 +118,15 @@ class TestEvaluateOnTheTimeline:
             assert total == t["placement"][key] > 0
         assert t["placement"]["window_slots"] <= t["placement"]["slots"]
 
+    def test_passes_carry_the_cross_fill_counts(self, tmp_path):
+        result, spans = self._evaluate(tmp_path, 48)
+        passes = [s for s in spans if s[0] == "repro.place.pass"]
+        t = result.timings["placement"]
+        for key in ("fill_attempts", "fill_skipped"):
+            assert all(key in s[3] for s in passes)
+            assert sum(s[3][key] for s in passes) == t[key]
+        assert 0 < t["fill_skipped"] <= t["fill_attempts"]
+
     def test_span_count_does_not_grow_with_n(self, tmp_path):
         _, small = self._evaluate(tmp_path / "small", 12)
         _, large = self._evaluate(tmp_path / "large", 48)
@@ -163,6 +172,22 @@ class TestRobustPlanOnTheTimeline:
             assert sum(s[3][key] for s in passes) == t["placement"][key] > 0
         assert sum(s[3]["wave_s"] for s in passes) == pytest.approx(
             t["placement"]["wave_s_total"])
+
+    def test_robust_passes_carry_the_cross_fill_counts(self, tmp_path):
+        from repro.stochastic import (StochasticConfig, gct_forecast,
+                                      plan_stochastic)
+
+        fc = gct_forecast(n=40, m=4, seed=3, burst_prob=0.1)
+        config = StochasticConfig(scenarios=6, quantiles=3)
+        plan_stochastic(fc, config)  # compile outside the trace
+        with jax.profiler.trace(str(tmp_path)):
+            res = plan_stochastic(fc, config)
+        passes = [s for s in _host_spans(tmp_path)
+                  if s[0] == "repro.place.pass"]
+        t = res.timings["placement"]
+        for key in ("fill_attempts", "fill_skipped"):
+            assert sum(s[3][key] for s in passes) == t[key]
+        assert 0 < t["fill_skipped"] <= t["fill_attempts"]
 
 
 class TestStepCounter:
