@@ -9,8 +9,8 @@ It drives the three entry points a user calls, at the paper's scale,
 with data generated from seeds by the repo's own generators:
 ``FleetEngine`` (offline grids, the compiled placement stepper),
 ``RightsizingService`` (an online GCT trace) and ``plan_stochastic``
-(the K=64 golden burst grid), then the two Pallas kernels against their
-``kernels/ref.py`` oracles.  Each phase times its first call as
+(the K=64 golden burst grid), then the Pallas congestion kernel
+against its ``kernels/ref.py`` oracle.  Each phase times its first call as
 compile and its later call as steady; every timing ends in a host
 sync.  These are the timings of one smoke run, not a benchmark.  Where
 the run's time limit forced a smaller size, the phase prints the cut
@@ -258,8 +258,8 @@ def phase_robust(scenarios=None) -> None:
         print(f"  check_stochastic: {f}", flush=True)
 
 
-def phase_kernels(spec=RUNTIME_INSTANCE, lanes=4, nodes=64) -> None:
-    """``congestion_many`` / ``fit_scores_many`` against the oracles."""
+def phase_kernels(spec=RUNTIME_INSTANCE, lanes=4) -> None:
+    """``congestion_many`` against its oracle."""
     from repro.core import trim_timeline
     from repro.kernels import ops
 
@@ -280,25 +280,6 @@ def phase_kernels(spec=RUNTIME_INSTANCE, lanes=4, nodes=64) -> None:
     say("kernels", kernel="congestion_many", shape=(lanes, t.n, D, T),
         compile_s=compile_s, steady_s=steady_s, max_rel_err=err)
     check(err <= 1e-5, f"kernels: congestion_many off the oracle by {err}")
-
-    rem = rng.random((lanes, nodes, T, D)).astype(np.float32)
-    dem = (0.5 * rng.random((lanes, D))).astype(np.float32)
-    s = rng.integers(0, T // 2, lanes)
-    e = s + rng.integers(0, T // 2, lanes)
-    inv = (1.0 / cap).astype(np.float32)
-    (feas, cos), compile_s = timed(ops.fit_scores_many, rem, dem, s, e, inv,
-                                   scored=True)
-    (feas, cos), steady_s = timed(ops.fit_scores_many, rem, dem, s, e, inv,
-                                  scored=True)
-    feas_r, cos_r = ops.fit_scores_many(rem, dem, s, e, inv, scored=True,
-                                        use_ref=True)
-    err = float(np.abs(cos - cos_r).max())
-    say("kernels", kernel="fit_scores_many", shape=(lanes, nodes, T, D),
-        compile_s=compile_s, steady_s=steady_s,
-        feas_mismatches=int((feas != feas_r).sum()), max_abs_err=err)
-    check(np.array_equal(feas, feas_r),
-          "kernels: fit_scores_many feasibility differs from the oracle")
-    check(err <= 1e-4, f"kernels: fit_scores_many off the oracle by {err}")
 
 
 def _lane_reference(trimmed, group, solver):

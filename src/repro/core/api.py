@@ -43,23 +43,22 @@ ALGORITHMS = ("penalty-map", "penalty-map-f", "lp-map", "lp-map-f")
 EXTENDED_ALGORITHMS = ALGORITHMS + ("lp-map-f+ls", "penalty-map-f+ls")
 
 
-def _penalty_solutions(problem: Problem, filling: bool, backend: str):
+def _penalty_solutions(problem: Problem, filling: bool):
     for kind in ("avg", "max"):
         mapping = penalty_map(problem, kind)
         for fit in FIT_POLICIES:
             yield two_phase(
-                problem, mapping, fit=fit, filling=filling, backend=backend,
+                problem, mapping, fit=fit, filling=filling,
                 meta={"algo": "penalty-map" + ("-f" if filling else ""),
                       "h": kind},
             )
 
 
-def _lp_solutions(problem: Problem, filling: bool, backend: str,
-                  lp_result=None):
+def _lp_solutions(problem: Problem, filling: bool, lp_result=None):
     res = lp_result if lp_result is not None else _solve_lp(problem)
     for fit in FIT_POLICIES:
         sol = two_phase(
-            problem, res.mapping, fit=fit, filling=filling, backend=backend,
+            problem, res.mapping, fit=fit, filling=filling,
             meta={"algo": "lp-map" + ("-f" if filling else ""),
                   "lp_objective": res.objective},
         )
@@ -69,7 +68,6 @@ def _lp_solutions(problem: Problem, filling: bool, backend: str,
 def rightsize(
     problem: Problem,
     algo: str = "lp-map-f",
-    backend: str = "numpy",
     check: bool = True,
     lp_result=None,
 ) -> Solution:
@@ -88,15 +86,13 @@ def rightsize(
     if local_search:
         algo = algo[: -len("+ls")]
     if algo == "penalty-map":
-        sols = _penalty_solutions(trimmed, filling=False, backend=backend)
+        sols = _penalty_solutions(trimmed, filling=False)
     elif algo == "penalty-map-f":
-        sols = _penalty_solutions(trimmed, filling=True, backend=backend)
+        sols = _penalty_solutions(trimmed, filling=True)
     elif algo == "lp-map":
-        sols = _lp_solutions(trimmed, filling=False, backend=backend,
-                             lp_result=lp_result)
+        sols = _lp_solutions(trimmed, filling=False, lp_result=lp_result)
     elif algo == "lp-map-f":
-        sols = _lp_solutions(trimmed, filling=True, backend=backend,
-                             lp_result=lp_result)
+        sols = _lp_solutions(trimmed, filling=True, lp_result=lp_result)
     else:
         raise ValueError(f"unknown algo {algo!r}; want one of {ALGORITHMS}")
     best = min(sols, key=lambda s: s.cost(trimmed))
@@ -129,11 +125,11 @@ def _solve_lp_for(problem: Problem, lp_solver: str, lp_iters: int,
     raise ValueError(f"unknown lp_solver {lp_solver!r}; want 'highs'|'pdhg'")
 
 
-def _protocol_entry(trimmed: Problem, lp_result, lb: float, algos,
-                    backend: str) -> dict:
+def _protocol_entry(trimmed: Problem, lp_result, lb: float,
+                    algos) -> dict:
     out: dict = {"lb": lb, "costs": {}, "normalized": {}, "wall_s": {}}
     for algo in algos:
-        sol = rightsize(trimmed, algo, backend=backend, lp_result=lp_result)
+        sol = rightsize(trimmed, algo, lp_result=lp_result)
         cost = sol.cost(trimmed)
         out["costs"][algo] = cost
         out["normalized"][algo] = cost / max(lb, 1e-12)
@@ -141,9 +137,8 @@ def _protocol_entry(trimmed: Problem, lp_result, lb: float, algos,
     return out
 
 
-def evaluate(problem: Problem, algos=ALGORITHMS, backend: str = "numpy",
-             lp_solver: str = "highs", lp_iters: int = 2000,
-             lp_tol: float | None = None) -> dict:
+def evaluate(problem: Problem, algos=ALGORITHMS, lp_solver: str = "highs",
+             lp_iters: int = 2000, lp_tol: float | None = None) -> dict:
     """Paper §VI protocol: per-algorithm best cost + the LP lower bound.
 
     ``lp_solver='highs'`` solves the mapping LP exactly (the paper's
@@ -162,7 +157,7 @@ def evaluate(problem: Problem, algos=ALGORITHMS, backend: str = "numpy",
     low = lower_constraints(problem)
     trimmed, _ = trim_timeline(low.lowered)
     lp_result, lb = _solve_lp_for(trimmed, lp_solver, lp_iters, lp_tol)
-    return _protocol_entry(trimmed, lp_result, lb, algos, backend)
+    return _protocol_entry(trimmed, lp_result, lb, algos)
 
 
 _UNSET = object()  # sentinel: distinguishes "kwarg passed" from default
@@ -170,7 +165,6 @@ _UNSET = object()  # sentinel: distinguishes "kwarg passed" from default
 # legacy kwarg -> the typed-config equivalent named in the deprecation
 # warning (behavior is bit-stable either way; only the spelling moves)
 _LEGACY_KWARGS = {
-    "backend": "PlacementConfig(backend=...)",
     "lp_iters": "SolverConfig(iters=...)",
     "operator": "SolverConfig(operator=...)",
     "placement": "PlacementConfig(engine=...)",
@@ -182,13 +176,13 @@ _LEGACY_KWARGS = {
 }
 
 _LEGACY_DEFAULTS = {
-    "backend": "numpy", "lp_iters": 2000, "operator": "auto",
+    "lp_iters": 2000, "operator": "auto",
     "placement": "batched", "lp_tol": None, "lp_adaptive": True,
     "lp_restart": True, "warm_start": None, "return_stats": False,
 }
 
 
-def evaluate_many(problems, algos=ALGORITHMS, backend=_UNSET,
+def evaluate_many(problems, algos=ALGORITHMS,
                   lp_iters=_UNSET, operator=_UNSET,
                   placement=_UNSET,
                   lp_tol=_UNSET,
@@ -219,7 +213,7 @@ def evaluate_many(problems, algos=ALGORITHMS, backend=_UNSET,
 
     Every kwarg maps onto one typed-config field (see the README
     migration table): ``lp_iters/operator/lp_tol/lp_adaptive/lp_restart``
-    -> ``SolverConfig``, ``placement/backend`` -> ``PlacementConfig``,
+    -> ``SolverConfig``, ``placement`` -> ``PlacementConfig``,
     ``warm_start`` -> ``SweepConfig``.  The shim always runs
     single-bucket (``SweepConfig(max_buckets=1)``) so the committed
     golden tables stay bit-identical; shape-bucketed packing of very
@@ -264,7 +258,7 @@ def evaluate_many(problems, algos=ALGORITHMS, backend=_UNSET,
                          SweepConfig)
 
     passed = {name: val for name, val in [
-        ("backend", backend), ("lp_iters", lp_iters),
+        ("lp_iters", lp_iters),
         ("operator", operator), ("placement", placement),
         ("lp_tol", lp_tol), ("lp_adaptive", lp_adaptive),
         ("lp_restart", lp_restart), ("warm_start", warm_start),
@@ -276,9 +270,9 @@ def evaluate_many(problems, algos=ALGORITHMS, backend=_UNSET,
             f"FleetEngine with the typed configs instead ({hints})",
             DeprecationWarning, stacklevel=2)
     resolved = dict(_LEGACY_DEFAULTS, **passed)
-    backend, lp_iters, operator, placement, lp_tol, lp_adaptive, \
+    lp_iters, operator, placement, lp_tol, lp_adaptive, \
         lp_restart, warm_start, return_stats = (
-            resolved[k] for k in ("backend", "lp_iters", "operator",
+            resolved[k] for k in ("lp_iters", "operator",
                                   "placement", "lp_tol", "lp_adaptive",
                                   "lp_restart", "warm_start",
                                   "return_stats"))
@@ -292,7 +286,7 @@ def evaluate_many(problems, algos=ALGORITHMS, backend=_UNSET,
         solver=SolverConfig(tol=lp_tol, iters=lp_iters,
                             adaptive=lp_adaptive, restart=lp_restart,
                             operator=operator),
-        placement=PlacementConfig(engine=placement, backend=backend),
+        placement=PlacementConfig(engine=placement),
         sweep=sweep,
         algos=algos,
     )

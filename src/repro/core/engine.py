@@ -11,7 +11,7 @@ object:
                           adaptive/restart machinery, operator form.
   * ``PlacementConfig`` — greedy phase: numpy-lockstep vs compiled
                           on-device vs per-instance engine, fit-policy
-                          scan, filling override, scoring backend.
+                          scan, filling override, plan check.
   * ``SweepConfig``     — fleet shape: shape-bucketed packing (this
                           module's planner), warm-started sweep
                           chaining, shard size of the LP dispatch.
@@ -67,7 +67,6 @@ __all__ = [
 
 _OPERATORS = ("auto", "dense", "cumsum", "pallas")
 _PLACEMENT_ENGINES = ("batched", "compiled", "loop")
-_PLACEMENT_BACKENDS = ("numpy", "kernel")
 # PlacementConfig.engine -> place_many stepper name ('loop' bypasses
 # place_many entirely)
 _ENGINE_STEPPER = {"batched": "lockstep", "compiled": "compiled"}
@@ -166,11 +165,8 @@ class PlacementConfig:
     minimum (the paper's §VI protocol); a concrete policy
     ('first'/'similarity') narrows the scan.  ``filling`` only applies
     to direct ``FleetEngine.place`` calls (the protocol derives
-    filling from the algorithm name); ``backend`` routes the numpy
-    stepper's scoring pass ('kernel' = the batch-dim-aware Pallas fit
-    kernel; the compiled stepper always scores on-device).  ``check``
-    verifies every returned placement against the instance
-    constraints.
+    filling from the algorithm name).  ``check`` verifies every
+    returned placement against the instance constraints.
 
     >>> PlacementConfig().engine
     'batched'
@@ -185,7 +181,6 @@ class PlacementConfig:
     engine: str = "batched"
     fit: str = "best"
     filling: bool = False
-    backend: str = "numpy"
     check: bool = True
 
     def __post_init__(self):
@@ -197,10 +192,6 @@ class PlacementConfig:
             raise ValueError(
                 f"fit must be 'best' or one of {FIT_POLICIES}, "
                 f"got {self.fit!r}")
-        if self.backend not in _PLACEMENT_BACKENDS:
-            raise ValueError(
-                f"placement backend must be one of {_PLACEMENT_BACKENDS}, "
-                f"got {self.backend!r}")
 
     @property
     def fits(self) -> tuple[str, ...]:
@@ -555,7 +546,7 @@ class FleetResult:
 # --- the protocol engine ---------------------------------------------------
 
 def _protocol_batched(batch: ProblemBatch, lp_results, algos, fits,
-                      backend: str, check: bool = True,
+                      check: bool = True,
                       stepper: str = "lockstep",
                       tels: list | None = None,
                       timings: dict | None = None) -> list[dict]:
@@ -585,8 +576,8 @@ def _protocol_batched(batch: ProblemBatch, lp_results, algos, fits,
         else:
             # extended algos (e.g. "+ls") keep the per-instance path
             for b, t in enumerate(batch.problems):
-                sol = rightsize(t, algo, backend=backend,
-                                lp_result=lp_results[b], check=check)
+                sol = rightsize(t, algo, lp_result=lp_results[b],
+                                check=check)
                 out[b]["costs"][algo] = sol.cost(t)
                 out[b]["wall_s"][algo] = sol.meta["wall_s"]
                 out[b]["plan"][algo] = sol
@@ -598,7 +589,7 @@ def _protocol_batched(batch: ProblemBatch, lp_results, algos, fits,
                 tel: dict = {}
                 with span("place.pass") as ann:
                     sols = place_many(batch, maps, fit=fit,
-                                      filling=filling, backend=backend,
+                                      filling=filling,
                                       meta={"algo": algo},
                                       placement=stepper, telemetry=tel)
                     ann.set_metadata(
@@ -977,15 +968,13 @@ class FleetEngine:
             problems = [low.lowered for low in lows]
         if cfg.engine == "loop":
             trimmed = self._trimmed(problems)
-            sols = [two_phase(t, mp, fit=fit, filling=filling,
-                              backend=cfg.backend)
+            sols = [two_phase(t, mp, fit=fit, filling=filling)
                     for t, mp in zip(trimmed, mappings)]
         else:
             batch = problems if isinstance(problems, ProblemBatch) \
                 else pack_problems(self._trimmed(problems),
                                    assume_trimmed=True)
             sols = place_many(batch, mappings, fit=fit, filling=filling,
-                              backend=cfg.backend,
                               placement=_ENGINE_STEPPER[cfg.engine])
         if lows is not None:
             sols = [expand_solution(low, s)
@@ -999,14 +988,12 @@ class FleetEngine:
         cfg = self.placement
         if cfg.engine in _ENGINE_STEPPER:
             return _protocol_batched(batch, lp_results, self.algos,
-                                     cfg.fits, cfg.backend,
-                                     check=cfg.check,
+                                     cfg.fits, check=cfg.check,
                                      stepper=_ENGINE_STEPPER[cfg.engine],
                                      tels=tels, timings=timings)
         from .api import _protocol_entry
 
-        return [_protocol_entry(t, res, res.lower_bound, self.algos,
-                                cfg.backend)
+        return [_protocol_entry(t, res, res.lower_bound, self.algos)
                 for t, res in zip(batch.problems, lp_results)]
 
     # -- the full protocol ---------------------------------------------

@@ -16,10 +16,10 @@ instances, so a step costs O(1) numpy dispatches regardless of B.
 Each step reads and writes only its window of the time axis, the live
 tasks' span union ``[t0, t1)``, not all T' slots: a task's span is
 typically a small part of the trimmed timeline.  A cross-fill sub-phase
-(numpy backend) takes no step for an attempt that fits no node: it
-tests each instance's pending candidates against a range-minimum table
-of its pool and places the first that fits, so each of its steps places
-a task in every live instance.
+takes no step for an attempt that fits no node: it tests each
+instance's pending candidates against a range-minimum table of its
+pool and places the first that fits, so each of its steps places a
+task in every live instance.
 
 Wave synchronization is the engine's load-bearing trick: instances are
 independent, so inserting barriers between their (own-pack, cross-fill)
@@ -72,12 +72,6 @@ properties make that hold:
     ``find_fit``'s ``all(rem >= dem - EPS)``: a minimum is exact, and
     the table's entries are the pool's own values, updated by the same
     ``rem - dem`` subtraction.
-
-``backend='kernel'`` routes the scoring pass through the batch-dim-aware
-Pallas kernel ``fit_scores_many`` (grid over B; fp32, matching the
-single-instance kernel backend; cross-fill then tries every attempt in a
-step of its own), ``backend='numpy'`` uses the bit-exact vectorized host
-path.
 """
 
 from __future__ import annotations
@@ -247,11 +241,9 @@ class _RangeMin:
 class _Engine:
     """Shared lockstep state across the waves of one place_many call."""
 
-    def __init__(self, batch: ProblemBatch, phases: list[_Phases],
-                 backend: str):
+    def __init__(self, batch: ProblemBatch, phases: list[_Phases]):
         self.batch = batch
         self.phases = phases
-        self.backend = backend
         Bn = batch.B
         self.n_cap = 8
         # the master open-node state: node id == purchase rank
@@ -291,17 +283,11 @@ class _Engine:
         # tasks), so this is exactly two_phase's dynamic ~placed filter
         # and no skip checks are needed inside the lockstep loop
         own = [self._live(b, self.phases[b].own[k]) for b in wave]
-        self._run_sub(wave, tau, pool, w, own, purchase=True,
-                      similarity=fit == "similarity")
-        pool = self._pool
+        pool = self._run_sub(wave, tau, pool, w, own,
+                             similarity=fit == "similarity")
         if filling:
             fill = [self._live(b, self.phases[b].fill[k]) for b in wave]
-            if self.backend == "numpy":
-                self._run_fill(wave, pool, w, fill)
-            else:
-                self._run_sub(wave, tau, pool, w, fill, purchase=False,
-                              similarity=False)
-                pool = self._pool
+            self._run_fill(wave, pool, w, fill)
         # scatter the finished type-block back into the master array
         hi = int((lo + w).max())
         while hi > self.n_cap:
@@ -321,37 +307,28 @@ class _Engine:
         """Order-preserving ~placed filter (two_phase's phase entry)."""
         return tasks[~self.placed[b, tasks]]
 
-    def _run_sub(self, wave, tau, pool, w, lists, purchase: bool,
-                 similarity: bool):
-        """Lockstep one sub-phase: one attempt list per wave instance,
-        scored against the wave's compact pool each step.
+    def _run_sub(self, wave, tau, pool, w, lists, similarity: bool):
+        """Lockstep one own-pack sub-phase: one attempt list per wave
+        instance, scored against the wave's compact pool each step, a
+        node bought on a miss.  Returns the wave's pool tensor, grown if
+        a live pool outgrew it.
 
         Instances leave a sub-phase permanently (their list is
         exhausted); finished pool rows are written back into the wave's
         pool tensor as their instance leaves, and the working set is
         compacted to the live rows once enough have finished, so the
         batched ops stay sized to the instances that still have
-        attempts.  Fill-only sub-phases (the kernel backend's; the
-        numpy backend's run in ``_run_fill``) drop node-less instances up
-        front: with an empty pool every attempt is a guaranteed miss
-        that mutates nothing, exactly as ``find_fit`` returns None on an
-        empty TypePool.  All per-task data (demands, spans, norms,
+        attempts.  All per-task data (demands, spans, norms,
         placement flags) is read straight from the engine's padded
         batch arrays through the live-row -> instance map, so dropping
         rows never copies them.
         """
         batch = self.batch
-        if purchase:
-            keep = np.flatnonzero(
-                np.array([len(x) for x in lists]) > 0)
-        else:
-            keep = np.flatnonzero(
-                (w > 0) & (np.array([len(x) for x in lists]) > 0))
+        keep = np.flatnonzero(np.array([len(x) for x in lists]) > 0)
         lists = [lists[a] for a in keep]
         A = len(keep)
         if A == 0:
-            self._pool = pool
-            return
+            return pool
         L = max(len(x) for x in lists)
         # live-row state; `keep` maps live rows back to wave rows and
         # `bsel_l` to instances
@@ -370,17 +347,11 @@ class _Engine:
         cap_rows = batch.cap[bsel_l, tau_l]      # (A, Dp), padded dims 1
         start_pad = batch.start.astype(np.int64)
         end_pad = batch.end.astype(np.int64)
-        kernel = self.backend == "kernel"
-        if kernel:
-            from repro.kernels import ops as kops
-
-            inv_cap = np.where(np.isfinite(capx), 1.0 / capx, 0.0)
         # pool_n caches pool / capx so similarity steps skip the big
         # division pass; the window of each updated row is re-divided
         # after the update, which is bitwise what find_fit computes from
         # the current rem
-        pool_n = pool_l / capx[:, None, None, :] \
-            if similarity and not kernel else None
+        pool_n = pool_l / capx[:, None, None, :] if similarity else None
 
         def write_back(rows):
             """Store finished live rows in the wave pool (grown if the
@@ -413,8 +384,6 @@ class _Engine:
                 wl, pool_l = wl[live], pool_l[live]
                 tau_l, bsel_l = tau_l[live], bsel_l[live]
                 capx, cap_rows = capx[live], cap_rows[live]
-                if kernel:
-                    inv_cap = inv_cap[live]
                 if pool_n is not None:
                     pool_n = pool_n[live]
                 A = len(live)
@@ -445,37 +414,30 @@ class _Engine:
             node_ok = (np.arange(W)[None, :] < wl[:, None]) \
                 & alive[:, None]
 
-            if kernel:
-                feas, score = kops.fit_scores_many(
-                    pool_l[:, :W], dem, s_cur, e_cur, inv_cap,
-                    scored=similarity)
-                feas = feas & node_ok
-            else:
-                # not any(rem < dem - EPS) over the span == find_fit's
-                # all(rem >= dem - EPS): the same comparisons on each
-                # node's contiguous ((t1-t0)*D)-flattened window (numpy's
-                # iterator is ~10x faster there than on 4-D broadcasts
-                # with a tiny trailing axis); the slots the window drops
-                # are masked out of every live row's span anyway
-                pool3 = pool_l[:, :W, t0:t1].reshape(A, W, -1)  # a view
-                thr_flat = np.tile(dem - EPS, (1, t1 - t0))
-                span_flat = np.repeat(span, batch.D, axis=1)
-                viol = ((pool3 < thr_flat[:, None, :])
-                        & span_flat[:, None, :]).any(axis=2)
-                feas = ~viol & node_ok
-                if similarity:
-                    # the window's dropped slots carry only exact-zero
-                    # terms, so the einsum accumulations are unchanged
-                    rem_n = pool_n[:, :W, t0:t1]
-                    dem_n = dem / capx
-                    span_f = span.astype(np.float64)
-                    dot = np.einsum("bntd,bd,bt->bn", rem_n, dem_n,
-                                    span_f)
-                    norm2 = np.einsum("bntd,bntd,bt->bn", rem_n, rem_n,
-                                      span_f)
-                    dem_norm = self.dn[bsel_l, u_cur]
-                    score = dot / (dem_norm[:, None] * np.sqrt(norm2)
-                                   + 1e-30)
+            # not any(rem < dem - EPS) over the span == find_fit's
+            # all(rem >= dem - EPS): the same comparisons on each node's
+            # contiguous ((t1-t0)*D)-flattened window (numpy's iterator
+            # is ~10x faster there than on 4-D broadcasts with a tiny
+            # trailing axis); the slots the window drops are masked out
+            # of every live row's span anyway
+            pool3 = pool_l[:, :W, t0:t1].reshape(A, W, -1)  # a view
+            thr_flat = np.tile(dem - EPS, (1, t1 - t0))
+            span_flat = np.repeat(span, batch.D, axis=1)
+            viol = ((pool3 < thr_flat[:, None, :])
+                    & span_flat[:, None, :]).any(axis=2)
+            feas = ~viol & node_ok
+            if similarity:
+                # the window's dropped slots carry only exact-zero
+                # terms, so the einsum accumulations are unchanged
+                rem_n = pool_n[:, :W, t0:t1]
+                dem_n = dem / capx
+                span_f = span.astype(np.float64)
+                dot = np.einsum("bntd,bd,bt->bn", rem_n, dem_n, span_f)
+                norm2 = np.einsum("bntd,bntd,bt->bn", rem_n, rem_n,
+                                  span_f)
+                dem_norm = self.dn[bsel_l, u_cur]
+                score = dot / (dem_norm[:, None] * np.sqrt(norm2)
+                               + 1e-30)
             has = feas.any(axis=1)
             if similarity:
                 # find_fit's quantized tie-break: digits beyond the 9th
@@ -487,29 +449,26 @@ class _Engine:
 
             place_a = np.flatnonzero(has)     # has implies alive
             j_all = choice[place_a]
-            if purchase:
-                buy_a = np.flatnonzero(~has & alive)
-                if len(buy_a):
-                    bad = (dem[buy_a] > cap_rows[buy_a] + EPS
-                           ).any(axis=1)
-                    if bad.any():
-                        a0 = int(buy_a[int(np.flatnonzero(bad)[0])])
-                        raise RuntimeError(
-                            f"mapping assigned task {int(u_cur[a0])} "
-                            f"to node-type {int(tau_l[a0])} it cannot "
-                            f"fit")
-                    j_new = wl[buy_a]
-                    # a new node is full over the whole timeline, and so
-                    # is its cached pool / capx row; the update below
-                    # then touches only the window of either
-                    pool_l[buy_a, j_new] = cap_rows[buy_a][:, None]
-                    if pool_n is not None:
-                        pool_n[buy_a, j_new] = (
-                            cap_rows[buy_a] / capx[buy_a])[:, None]
-                    wl[buy_a] += 1
-                    self.counts[bsel_l[buy_a]] += 1
-                    place_a = np.concatenate([place_a, buy_a])
-                    j_all = np.concatenate([j_all, j_new])
+            buy_a = np.flatnonzero(~has & alive)
+            if len(buy_a):
+                bad = (dem[buy_a] > cap_rows[buy_a] + EPS).any(axis=1)
+                if bad.any():
+                    a0 = int(buy_a[int(np.flatnonzero(bad)[0])])
+                    raise RuntimeError(
+                        f"mapping assigned task {int(u_cur[a0])} "
+                        f"to node-type {int(tau_l[a0])} it cannot fit")
+                j_new = wl[buy_a]
+                # a new node is full over the whole timeline, and so is
+                # its cached pool / capx row; the update below then
+                # touches only the window of either
+                pool_l[buy_a, j_new] = cap_rows[buy_a][:, None]
+                if pool_n is not None:
+                    pool_n[buy_a, j_new] = (
+                        cap_rows[buy_a] / capx[buy_a])[:, None]
+                wl[buy_a] += 1
+                self.counts[bsel_l[buy_a]] += 1
+                place_a = np.concatenate([place_a, buy_a])
+                j_all = np.concatenate([j_all, j_new])
             if len(place_a):
                 sub = (dem[place_a][:, None, :]
                        * span[place_a].astype(np.float64)[:, :, None])
@@ -526,15 +485,13 @@ class _Engine:
                 self.assign[b_sel, u_sel] = \
                     self.counts[b_sel] - wl[place_a] + j_all
                 self.placed[b_sel, u_sel] = True
-            if not purchase:
-                self.fill_attempts += int(alive.sum())
             ptr += alive
             self.steps += 1
-        self._pool = pool
+        return pool
 
     def _run_fill(self, wave, pool, w, lists):
-        """Lockstep one cross-fill sub-phase (first fit, no purchase) on
-        the numpy backend, in place on the wave's pool tensor.
+        """Lockstep one cross-fill sub-phase (first fit, no purchase),
+        in place on the wave's pool tensor.
 
         No node is bought here, so a row's pool only loses capacity, and
         only where the row places a task.  Each step takes every live
@@ -631,8 +588,8 @@ class _Engine:
 
 
 def place_many(problems, mappings, fit: str = "first",
-               filling: bool = False, backend: str = "numpy",
-               meta: dict | None = None, placement: str = "lockstep",
+               filling: bool = False, meta: dict | None = None,
+               placement: str = "lockstep",
                telemetry: dict | None = None) -> list[Solution]:
     """Batched ``two_phase`` over B instances; placements are identical.
 
@@ -649,15 +606,12 @@ def place_many(problems, mappings, fit: str = "first",
     on-device ``lax.scan`` (``repro.core.place_step``) so the host
     dispatches only at phase boundaries — placements are bit-identical,
     and oversized pools fall back to the numpy engine automatically.
-    ``backend`` routes the numpy stepper's scoring pass (``'kernel'`` =
-    the batch-dim-aware Pallas fit kernel); the compiled stepper scores
-    on-device and ignores it.  ``telemetry``, when a dict, is filled
-    in place with the stepper actually used, wave count, per-wave
-    seconds, the numpy engine's lockstep step count, the timeline slots
-    its steps read (``window_slots``) against T' a step (``slots``),
-    its cross-fill attempts (``fill_attempts``) and those skipped
-    without a step (``fill_skipped``), and (compiled) device-dispatch
-    counts.
+    ``telemetry``, when a dict, is filled in place with the stepper
+    actually used, wave count, per-wave seconds, the numpy engine's
+    lockstep step count, the timeline slots its steps read
+    (``window_slots``) against T' a step (``slots``), its cross-fill
+    attempts (``fill_attempts``) and those skipped without a step
+    (``fill_skipped``), and (compiled) device-dispatch counts.
 
     >>> import numpy as np
     >>> from repro.core import place_many, two_phase
@@ -672,9 +626,6 @@ def place_many(problems, mappings, fit: str = "first",
     """
     if fit not in FIT_POLICIES:
         raise ValueError(f"fit must be one of {FIT_POLICIES}")
-    if backend not in ("numpy", "kernel"):
-        raise ValueError(
-            f"backend must be 'numpy'|'kernel', got {backend!r}")
     if placement not in PLACEMENT_STEPPERS:
         raise ValueError(
             f"placement must be one of {PLACEMENT_STEPPERS}, "
@@ -695,7 +646,7 @@ def place_many(problems, mappings, fit: str = "first",
             return sols
         # oversized pool: place_step declined (and recorded why in
         # telemetry); fall through to the numpy lockstep engine
-    eng = _Engine(batch, phases, backend)
+    eng = _Engine(batch, phases)
     wave_s = []
     k = 0
     while True:

@@ -28,8 +28,7 @@ index with lanes masked by their list lengths):
 
 Each scan step scores the pending task of every lane against all its
 candidate nodes in one batched feasibility + similarity pass
-(``kernels.ops.fit_scores_step``, the in-loop callable form of
-``fit_scores_many``) and picks nodes with the engines' shared argmax
+(``fit_scores_step``) and picks nodes with the engines' shared argmax
 tie-break; purchases and capacity updates are masked tensor updates
 inside the scan.  The scan is split into static *chunks* replicating
 the numpy engine's work-saving slices (see ``_plan_chunks``): the live
@@ -75,7 +74,7 @@ import numpy as np
 
 from .solution import EPS, Solution
 
-__all__ = ["run_compiled", "MAX_POOL_CELLS"]
+__all__ = ["run_compiled", "fit_scores_step", "MAX_POOL_CELLS"]
 
 # Fall back to the numpy lockstep engine when a wave's padded pool
 # tensor (B, N_cap, T', D) would exceed this many float64 elements: the
@@ -95,13 +94,71 @@ def _pad4(x: int) -> int:
     return max(4, (int(x) + 3) & ~3)
 
 
+def fit_scores_step(rem, dem, span, capx, dem_norm, scored: bool = False,
+                    quantum=None, eps: float = EPS):
+    """Feasibility and similarity of every lane's pending task against
+    each of its open nodes: the scoring pass of one compiled step.
+
+    A pure-jnp function meant to be *traced* — it takes and returns
+    ``jnp`` arrays, does no host conversion or padding, and is safe
+    inside ``lax.while_loop`` / ``lax.scan`` bodies (the sub-phase scan
+    calls it once per placement step).
+
+    All slot-carrying operands arrive flattened to one contiguous
+    reduction axis K = T*D (slot k = t*D + d), the same layout trick
+    the numpy engine uses for its feasibility scan: the similarity dot
+    then lowers to a batched mat-vec over a contiguous axis instead of
+    a 4-D einsum with a tiny trailing dimension, which CPU/TPU backends
+    vectorize an order of magnitude better.
+
+    rem:      (B, N, K) open-node remaining capacity.
+    dem:      (B, K) the pending task's demand, tiled over timeslots.
+    span:     (B, K) bool, True inside each instance's task span.
+    capx:     (B, K) node-type capacity tiled over slots, +inf on
+              padded dims, so ``rem / capx`` is exact on real dims and
+              0 on padded ones.
+    dem_norm: (B,) the precomputed per-task demand norm of the
+              similarity denominator.
+    quantum:  similarity tie-break quantization as a *runtime* scalar
+              (1e9 for the engines' shared 9-decimal rounding).  Passing
+              it as an operand keeps XLA from folding the division into
+              a multiply-by-reciprocal, which is not bit-equal to the
+              host engines' ``np.round(score, 9)``.
+
+    Returns ``(feas, score)``, both (B, N): feasibility is the same
+    elementwise float comparison the host engines evaluate
+    (``not any(rem < dem - eps)`` over the span), and ``score`` is the
+    quantized cosine similarity (zeros when ``scored`` is False).  In a
+    float64 trace (``jax.enable_x64(True)``) every elementwise
+    term is bit-identical to the numpy engines; the reduction sums may
+    differ in the last ulp, which the shared quantization collapses.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    thr = dem - eps
+    viol = ((rem < thr[:, None, :]) & span[:, None, :]).any(axis=2)
+    feas = ~viol
+    if not scored:
+        return feas, jnp.zeros(feas.shape, rem.dtype)
+    span_f = span.astype(rem.dtype)
+    rem_n = rem / capx[:, None, :]
+    q = (dem / capx) * span_f                 # exact: dem_n * {0, 1}
+    dot = jnp.einsum("bnk,bk->bn", rem_n, q,  # batched mat-vec
+                     precision=jax.lax.Precision.HIGHEST)
+    rm = rem_n * span_f[:, None, :]
+    norm2 = (rm * rm).sum(axis=2)
+    score = dot / (dem_norm[:, None] * jnp.sqrt(norm2) + 1e-30)
+    if quantum is not None:
+        score = jnp.rint(score * quantum) / quantum
+    return feas, score
+
+
 def _make_sub_phase():
     """Build the jitted sub-phase scan (deferred so importing this
     module never imports jax eagerly on the fallback-only path)."""
     import jax
     import jax.numpy as jnp
-
-    from repro.kernels.ops import fit_scores_step
 
     @functools.partial(jax.jit,
                        static_argnames=("purchase", "similarity",

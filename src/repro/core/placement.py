@@ -11,9 +11,9 @@ fitting policies (paper §III):
                      (the dot-product/best-fit strategy of [25], [12]).
 
 The per-task scoring pass is the algorithm's hot loop
-(O(n * |S| * D * T) total); ``backend='kernel'`` routes it through the
-Pallas fit kernel (repro.kernels), ``backend='numpy'`` uses the plain
-vectorized host path.  Both produce identical placements.
+(O(n * |S| * D * T) total), here one vectorized numpy pass over the
+type's open nodes.  ``two_phase`` is the plain per-instance reference
+that the lockstep engines (``place_batch``, ``place_step``) match.
 """
 
 from __future__ import annotations
@@ -32,14 +32,13 @@ FIT_POLICIES = ("first", "similarity")
 class TypePool:
     """Open nodes of one node-type, with remaining capacity over (T, D)."""
 
-    def __init__(self, cap_vec: np.ndarray, T: int, backend: str = "numpy"):
+    def __init__(self, cap_vec: np.ndarray, T: int):
         self.cap_vec = np.asarray(cap_vec, dtype=np.float64)  # (D,)
         self.T = T
         self.D = len(self.cap_vec)
         self._rem = np.empty((4, T, self.D))
         self.count = 0
         self.global_ids: list[int] = []
-        self.backend = backend
 
     @property
     def rem(self) -> np.ndarray:
@@ -59,36 +58,25 @@ class TypePool:
         """Local index of the chosen feasible node, or None."""
         if self.count == 0:
             return None
-        if self.backend == "kernel":
-            from repro.kernels import ops as kops
-
-            feas, score = kops.fit_scores(
-                self.rem, dem, s, e, self.cap_vec, scored=(fit == "similarity")
-            )
-            feas = np.asarray(feas)
-            score = np.asarray(score)
-        else:
-            rem_slice = self.rem[:, s : e + 1, :]
-            feas = (rem_slice >= dem[None, None, :] - EPS).all(axis=(1, 2))
-            if fit == "similarity":
-                dem_n = dem / self.cap_vec  # (D,)
-                rem_n = rem_slice / self.cap_vec[None, None, :]
-                dot = np.einsum("ntd,d->n", rem_n, dem_n)
-                # cosine: demand vector is constant across the span
-                span = e - s + 1
-                dem_norm = np.linalg.norm(dem_n) * np.sqrt(span)
-                rem_norm = np.sqrt(np.einsum("ntd,ntd->n", rem_n, rem_n))
-                score = dot / (dem_norm * rem_norm + 1e-30)
-            else:
-                score = None
+        rem_slice = self.rem[:, s : e + 1, :]
+        feas = (rem_slice >= dem[None, None, :] - EPS).all(axis=(1, 2))
         if not feas.any():
             return None
         if fit == "first":
             return int(np.argmax(feas))  # lowest index == earliest purchased
+        dem_n = dem / self.cap_vec  # (D,)
+        rem_n = rem_slice / self.cap_vec[None, None, :]
+        dot = np.einsum("ntd,d->n", rem_n, dem_n)
+        # cosine: demand vector is constant across the span
+        span = e - s + 1
+        dem_norm = np.linalg.norm(dem_n) * np.sqrt(span)
+        rem_norm = np.sqrt(np.einsum("ntd,ntd->n", rem_n, rem_n))
+        score = dot / (dem_norm * rem_norm + 1e-30)
         # quantize before the argmax: digits beyond the 9th are float
-        # reassociation noise (einsum kernels differ by layout), and
-        # rounding makes the first-max tie-break identical across the
-        # numpy / Pallas / batched-lockstep scoring paths
+        # reassociation noise (the similarity sums of this loop, the
+        # numpy lockstep engine and the compiled stepper reduce in
+        # different orders), and rounding makes the first-max tie-break
+        # pick the same node on all three
         masked = np.where(feas, np.round(score, 9), -np.inf)
         return int(np.argmax(masked))
 
@@ -106,7 +94,6 @@ def two_phase(
     mapping: np.ndarray,
     fit: str = "first",
     filling: bool = False,
-    backend: str = "numpy",
     meta: dict | None = None,
 ) -> Solution:
     """Run the placement phase for a given task->node-type ``mapping``.
@@ -136,9 +123,7 @@ def two_phase(
 
     assign = np.full(n, -1, dtype=np.int64)
     node_types_purchased: list[int] = []
-    pools = {
-        B: TypePool(nt.cap[B], problem.T, backend=backend) for B in range(nt.m)
-    }
+    pools = {B: TypePool(nt.cap[B], problem.T) for B in range(nt.m)}
     h_avg = penalty_mod.relative_demand(problem, "avg") if filling else None
     placed = np.zeros(n, dtype=bool)
 

@@ -1,15 +1,13 @@
-"""Pallas TPU kernels for the paper's compute hot-spots.
+"""Pallas TPU kernel for the paper's congestion hot-spot.
 
 * ``congestion``  — interval-congestion matmul (LP constraints, Lemma-1
-                    bound, PDHG operator).
-* ``fit_scores``  — placement feasibility + similarity scoring over all
-                    open nodes (the O(n·|S|·D·T) placement hot loop).
+                    bound, PDHG operator; the LP's ``operator="pallas"``).
 
 ``ops`` holds the jit'd wrappers (interpret=True off-TPU); ``ref`` the
 pure-jnp oracles the tests sweep against.
 """
 
 from . import ops, ref
-from .ops import congestion, fit_scores
+from .ops import congestion
 
-__all__ = ["ops", "ref", "congestion", "fit_scores"]
+__all__ = ["ops", "ref", "congestion"]

@@ -58,8 +58,7 @@ def configs_from_flags(args) -> dict:
                                scaling=args.scaling,
                                precision=args.precision,
                                omega=not args.no_omega),
-        "placement": PlacementConfig(engine=args.placement,
-                                     backend=args.backend),
+        "placement": PlacementConfig(engine=args.placement),
         "sweep": SweepConfig(max_buckets=args.buckets,
                              shard_size=args.shard_size,
                              warm_start=args.warm_start,
@@ -100,10 +99,6 @@ def _shared_flags() -> argparse.ArgumentParser:
     p.add_argument("--placement", default="batched",
                    choices=["batched", "compiled", "loop"],
                    help="placement engine (PlacementConfig.engine)")
-    p.add_argument("--backend", default="numpy",
-                   choices=["numpy", "kernel"],
-                   help="placement scoring backend "
-                        "(PlacementConfig.backend)")
     p.add_argument("--buckets", type=int, default=1,
                    help="max shape buckets (SweepConfig.max_buckets)")
     p.add_argument("--shard-size", type=int, default=None,

@@ -371,7 +371,7 @@ def plan_stochastic(forecast: DemandForecast | ScenarioSet,
         with span("place", timings, "place_s"):
             entries = _protocol_batched(
                 batch, lp_results, (config.algo,), FIT_POLICIES,
-                engine.placement.backend, check=engine.placement.check,
+                check=engine.placement.check,
                 stepper=_ENGINE_STEPPER.get(engine.placement.engine,
                                             "lockstep"),
                 tels=tels, timings=timings)

@@ -66,7 +66,7 @@ class TestConfigValidation:
             SolverConfig(**kw)
 
     @pytest.mark.parametrize("kw", [
-        {"engine": "bogus"}, {"fit": "bogus"}, {"backend": "bogus"},
+        {"engine": "bogus"}, {"fit": "bogus"},
     ])
     def test_placement_config_rejects(self, kw):
         with pytest.raises(ValueError):
@@ -350,13 +350,6 @@ class TestPlaceAndBackends:
             # slot-by-slot capacity audit holds there too
             assert_feasible(p, a)
 
-    def test_place_many_rejects_unknown_backend(self):
-        problems = _ragged_grid(shapes=2, seeds=1)
-        lp, _ = FleetEngine(solver=SolverConfig(iters=120)).solve(problems)
-        with pytest.raises(ValueError, match="backend"):
-            place_many(problems, [r.mapping for r in lp],
-                       backend="bogus")
-
 
 class TestCompiledPlacementEngine:
     """PlacementConfig(engine='compiled') routes the protocol through
@@ -579,10 +572,10 @@ class TestEvaluateManyDeprecation:
     def test_warning_joins_every_passed_kwarg(self):
         with pytest.warns(DeprecationWarning) as rec:
             evaluate_many(self._one(), algos=("penalty-map-f",),
-                          placement="loop", backend="numpy")
+                          placement="loop", lp_iters=300)
         msg = str(rec[0].message)
         assert "placement -> PlacementConfig(engine=...)" in msg
-        assert "backend -> PlacementConfig(backend=...)" in msg
+        assert "lp_iters -> SolverConfig(iters=...)" in msg
         assert "FleetEngine" in msg
 
     def test_shim_is_bit_stable_vs_engine(self):

@@ -9,10 +9,6 @@ on-device stepper ``repro.core.place_step``:
     'compiled')`` (on-device stepper), and the looped ``two_phase``
     (same node purchases, same ``assign``, same cost) for all four
     {fit} x {filling} combos, and ``verify`` holds on every solution;
-  * kernel oracle sweep — ``fit_scores_many`` vs its numpy/jnp
-    reference across shapes, padded-dim masks, span edges (s == e,
-    full-timeline tasks) and interpret-mode CPU execution, mirroring
-    the ``congestion_many_pallas`` oracle tests;
   * protocol parity — ``evaluate_many(placement='batched')`` produces
     the same costs as the per-instance placement loop;
   * stepper dispatch — unknown ``place_many(placement=...)`` values
@@ -50,8 +46,6 @@ from repro.core import (  # noqa: E402
     verify,
 )
 from repro.core.placement import FIT_POLICIES  # noqa: E402
-from repro.kernels import ops, ref  # noqa: E402
-from repro.kernels.fit import fit_scores_many_pallas  # noqa: E402
 from repro.workload import SyntheticSpec, synthetic_batch, \
     synthetic_instance  # noqa: E402
 
@@ -220,23 +214,6 @@ class TestPlaceManyFixtures:
         with pytest.raises(RuntimeError):
             place_many([t], [bad])
 
-    @pytest.mark.slow
-    def test_kernel_backend_parity(self):
-        """backend='kernel' (fp32 Pallas scoring, interpret on CPU)
-        places identically to the numpy loop."""
-        problems = [synthetic_instance(SyntheticSpec(n=n, m=m, D=D, T=T,
-                                                     seed=s))
-                    for s, (n, m, D, T) in enumerate(
-                        [(25, 3, 2, 10), (30, 2, 3, 8), (20, 4, 2, 12)])]
-        batch = pack_problems(problems)
-        maps = [penalty_map(t, "avg") for t in batch.problems]
-        for fit, filling in ALL_COMBOS:
-            sols = place_many(batch, maps, fit=fit, filling=filling,
-                              backend="kernel")
-            for t, mp, got in zip(batch.problems, maps, sols):
-                want = two_phase(t, mp, fit=fit, filling=filling)
-                _assert_equal_solutions(got, want)
-
 
 def _window_grid():
     """Two instances on an 80-slot timeline whose narrow tasks (1-4
@@ -378,13 +355,6 @@ class TestCrossFillSkip:
         place_many(problems, maps, fit=fit, filling=False, telemetry=tel)
         assert tel["fill_attempts"] == tel["fill_skipped"] == 0
 
-    def test_kernel_backend_tries_every_attempt(self):
-        problems, maps = _miss_grid(2)
-        tel = {}
-        place_many(problems, maps, fit="first", filling=True,
-                   backend="kernel", telemetry=tel)
-        assert tel["fill_attempts"] > 0 and tel["fill_skipped"] == 0
-
 
 class TestRangeMin:
     @pytest.mark.parametrize("T", [1, 2, 7, 32, 45])
@@ -419,101 +389,6 @@ class TestRangeMin:
         assert small.buf is big.buf
         assert _RangeMin(rng.uniform(size=(5, 20, 2)), big.buf).buf \
             is not big.buf
-
-
-class TestFitScoresManyKernel:
-    """Oracle sweep for the batch-dim-aware Pallas fit kernel, mirroring
-    the congestion_many_pallas tests (interpret-mode CPU execution)."""
-
-    @pytest.mark.parametrize("B,N,T,D", [
-        (1, 1, 1, 1),
-        (3, 7, 24, 2),       # sub-block everything
-        (2, 16, 40, 5),
-        (4, 30, 13, 3),
-        (2, 130, 20, 2),     # over the 128-lane node block edge
-    ])
-    def test_matches_ref(self, B, N, T, D):
-        rem = RNG.random((B, N, T, D)).astype(np.float32)
-        dem = (RNG.random((B, D)) * 0.2).astype(np.float32)
-        inv = (1.0 / (0.5 + RNG.random((B, D)))).astype(np.float32)
-        s = RNG.integers(0, T, B)
-        e = np.array([RNG.integers(lo, T) for lo in s])
-        fk, ck = ops.fit_scores_many(rem, dem, s, e, inv, scored=True)
-        fr, cr = ops.fit_scores_many(rem, dem, s, e, inv, scored=True,
-                                     use_ref=True)
-        np.testing.assert_array_equal(fk, fr)
-        np.testing.assert_allclose(ck, cr, rtol=1e-4, atol=1e-5)
-
-    def test_span_edges(self):
-        """Point spans (s == e) and full-timeline tasks."""
-        B, N, T, D = 3, 9, 12, 3
-        rem = RNG.random((B, N, T, D)).astype(np.float32)
-        dem = (RNG.random((B, D)) * 0.2).astype(np.float32)
-        inv = np.ones((B, D), np.float32)
-        for s, e in [(np.array([0, 5, T - 1]), np.array([0, 5, T - 1])),
-                     (np.zeros(B, int), np.full(B, T - 1))]:
-            fk, ck = ops.fit_scores_many(rem, dem, s, e, inv, scored=True)
-            fr, cr = ops.fit_scores_many(rem, dem, s, e, inv,
-                                         scored=True, use_ref=True)
-            np.testing.assert_array_equal(fk, fr)
-            np.testing.assert_allclose(ck, cr, rtol=1e-4, atol=1e-5)
-
-    def test_padded_dims_are_neutral(self):
-        """inv_cap=0 marks padded dims: they contribute nothing to the
-        similarity reductions, and zero demand there keeps feasibility
-        neutral — exactly the engine's padding contract."""
-        B, N, T = 2, 6, 10
-        rem3 = RNG.random((B, N, T, 3)).astype(np.float32)
-        rem4 = np.concatenate(
-            [rem3, np.ones((B, N, T, 1), np.float32)], axis=3)
-        dem3 = (RNG.random((B, 3)) * 0.2).astype(np.float32)
-        dem4 = np.concatenate([dem3, np.zeros((B, 1), np.float32)], 1)
-        inv3 = np.ones((B, 3), np.float32)
-        inv4 = np.concatenate([inv3, np.zeros((B, 1), np.float32)], 1)
-        s = np.array([2, 0])
-        e = np.array([7, T - 1])
-        f3, c3 = ops.fit_scores_many(rem3, dem3, s, e, inv3, scored=True)
-        f4, c4 = ops.fit_scores_many(rem4, dem4, s, e, inv4, scored=True)
-        np.testing.assert_array_equal(f3, f4)
-        np.testing.assert_allclose(c3, c4, rtol=1e-5, atol=1e-6)
-
-    def test_instances_are_independent(self):
-        """Each grid-over-B group must see only its own instance."""
-        N, T, D = 8, 14, 2
-        rem = RNG.random((1, N, T, D)).astype(np.float32)
-        dem = (RNG.random((1, D)) * 0.3).astype(np.float32)
-        inv = np.ones((1, D), np.float32)
-        s, e = np.array([3]), np.array([9])
-        alone_f, alone_c = ops.fit_scores_many(rem, dem, s, e, inv,
-                                               scored=True)
-        rem3 = np.concatenate([rem * 0.5, rem, rem + 1], 0)
-        dem3 = np.concatenate([dem * 2, dem, dem * 0.1], 0)
-        inv3 = np.concatenate([inv, inv, inv * 0.7], 0)
-        s3 = np.array([0, 3, 5])
-        e3 = np.array([T - 1, 9, 6])
-        f3, c3 = ops.fit_scores_many(rem3, dem3, s3, e3, inv3,
-                                     scored=True)
-        np.testing.assert_array_equal(f3[1], alone_f[0])
-        np.testing.assert_allclose(c3[1], alone_c[0], rtol=1e-6,
-                                   atol=1e-6)
-
-    def test_small_block_sizes(self):
-        """Multi-step grids with tiny blocks, raw kernel vs raw oracle."""
-        B, N, T, D = 3, 20, 40, 3
-        rem = RNG.random((B, N, T, D)).astype(np.float32)
-        dem = (RNG.random((B, D)) * 0.1).astype(np.float32)
-        inv = np.ones((B, D), np.float32)
-        mask = np.zeros((B, T), np.float32)
-        mask[0, 5:30] = 1.0
-        mask[1, 0:1] = 1.0
-        mask[2, :] = 1.0
-        got = fit_scores_many_pallas(
-            np.ascontiguousarray(rem.transpose(0, 2, 3, 1)), dem, mask,
-            inv, block_n=8, block_t=8, interpret=True)
-        want = ref.fit_scores_many_ref(rem, dem, mask, inv)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=1e-4, atol=1e-5)
 
 
 class TestEvaluateManyPlacement:

@@ -83,37 +83,23 @@ def _congestion_many(S, n, Tp):
         S((G, n, 2), jnp.float32), T=Tp)
 
 
-def _fit_scores_many(S, n, Tp):
-    from repro.kernels.fit import fit_scores_many_pallas
-
-    B, N, D = 4, 64, 2
-    return fit_scores_many_pallas.lower(
-        S((B, Tp, D, N), jnp.float32), S((B, D), jnp.float32),
-        S((B, Tp), jnp.float32), S((B, D), jnp.float32))
-
-
-def _fit_scores(S, n, Tp):
-    from repro.kernels.fit import fit_scores_pallas
-
-    N, D = 64, 2
-    return fit_scores_pallas.lower(
-        S((Tp, D, N), jnp.float32), S((D,), jnp.float32),
-        S((Tp,), jnp.float32), S((D,), jnp.float32))
-
-
-@pytest.mark.parametrize("lower", [_congestion_many, _fit_scores_many,
-                                   _fit_scores],
-                         ids=["congestion_many_G4", "fit_scores_many_B4",
-                              "fit_scores_T1973"])
+@pytest.mark.parametrize("lower", [_congestion_many],
+                         ids=["congestion_many_G4"])
 def test_kernel_compiles_to_mosaic(one_chip, runtime_batch, lower):
     S = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
     compiled = lower(S, runtime_batch.n, runtime_batch.Tp).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_compiled_placement_sub_phase_compiles(one_chip, runtime_batch):
-    """One own-pack sub-phase of the compiled placement stepper, traced
-    in f64, over the runtime instance's first 64 start-sorted tasks."""
+@pytest.mark.parametrize("purchase,similarity",
+                         [(True, True), (True, False), (False, False)],
+                         ids=["own_similarity", "own_first", "cross_fill"])
+def test_compiled_placement_sub_phase_compiles(one_chip, runtime_batch,
+                                               purchase, similarity):
+    """One sub-phase of the compiled placement stepper, traced in f64,
+    over the runtime instance's first 64 start-sorted tasks: own-pack
+    under either fit policy, and cross-fill (first fit, no purchase) on
+    a pool whose 64 nodes are all open."""
     from repro.core.place_step import _pad4, _plan_chunks, _sub_phase_fn
 
     b = runtime_batch
@@ -123,7 +109,8 @@ def test_compiled_placement_sub_phase_compiles(one_chip, runtime_batch):
     lens = np.array([L], np.int32)
     s_seq = b.start[0][order][:, None].astype(np.int32)
     e_seq = b.end[0][order][:, None].astype(np.int32)
-    chunks = _plan_chunks(lens, s_seq, e_seq, n_cap, Tpp, 0, grows=True)
+    chunks = _plan_chunks(lens, s_seq, e_seq, n_cap, Tpp,
+                          0 if purchase else n_cap, grows=purchase)
     S = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
     with jax.enable_x64(True):
         f64, i32 = jnp.float64, jnp.int32
@@ -131,5 +118,6 @@ def test_compiled_placement_sub_phase_compiles(one_chip, runtime_batch):
             S((1, n_cap, Tpp * D), f64), S((1,), i32), S((1,), i32),
             S((L, 1, D), f64), S((L, 1), i32), S((L, 1), i32),
             S((L, 1), f64), S((1, D), f64), S((1, D), f64), S((), f64),
-            purchase=True, similarity=True, chunks=chunks).compile()
+            purchase=purchase, similarity=similarity,
+            chunks=chunks).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
