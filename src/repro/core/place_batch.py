@@ -51,18 +51,25 @@ properties make that hold:
     as ``TypePool.find_fit``: feasibility as ``not any(rem < dem -
     EPS)`` over the span (a bool reduction of the identical
     comparisons, on identical remaining-capacity values — elementwise
-    updates never reassociate), and similarity via einsums whose masked
-    terms are exact zeros.  The step window changes none of it: a slot
-    outside ``[t0, t1)`` lies outside every live task's span, so the
+    updates never reassociate), and similarity from the same
+    ``rem / cap`` values, kept time-innermost with a per-slot sum of
+    squares over D beside them, through two-operand einsums: over
+    contiguous time, then over D.  Masked terms are exact zeros
+    (``x * 0.0``).  The step window changes none of it: a slot outside
+    ``[t0, t1)`` lies outside every live task's span, so the
     full-timeline pass masks it out of feasibility, adds an exact zero
-    for it to the einsums, and subtracts ``dem*0`` from it, no change.
-    A node bought in the step is set full over the whole timeline (and
-    so is its cached ``rem / cap`` row) before the windowed update.
-    Similarity sums can still differ from the loop in the last ulp
-    (numpy's einsum kernels vary with memory layout), so BOTH engines
-    quantize scores to 9 decimals before the argmax — reassociation
-    noise collapses onto identical values and the first-max tie-break
-    picks the same node on every path.
+    for it to the sums, and subtracts ``dem*0`` from it, no change.  A
+    node bought in the step is set full over the whole timeline (and so
+    are its cached ``rem / cap`` and sum-of-squares rows) before the
+    windowed update.  These sums are reassociated against the loop's
+    (over time first, then D) and can differ from it in the last ulp,
+    so BOTH engines quantize scores to 9 decimals before the argmax —
+    reassociation noise collapses onto identical values and the
+    first-max tie-break picks the same node on every path.  Within a
+    step every node's row is reduced in the same order, so nodes whose
+    remaining capacity is equal over the span get bit-identical scores;
+    that is why the sums stay off BLAS, whose batched gemv orders a
+    row's sum by the row's position and can split such a tie.
   * skipping cross-fill misses changes no placement.  A cross-fill
     sub-phase buys no node, so capacity only shrinks in it, and a miss
     mutates nothing: the candidates an instance tries before its next
@@ -165,6 +172,16 @@ def _batch_aux(batch: ProblemBatch, phases: list[_Phases]):
     span_all = ((batch.start[:, :, None] <= t_ids)
                 & (t_ids <= batch.end[:, :, None]))
     return dn, capx, span_all
+
+
+def _sum_sq(x: np.ndarray) -> np.ndarray:
+    """Sum of squares over the D axis of ``x`` (..., D, t), one slot at a
+    time in dimension order: elementwise adds, so a slot's sum depends
+    only on its own D values, whatever the shape or layout around it."""
+    out = np.square(x[..., 0, :])
+    for d in range(1, x.shape[-2]):
+        out += np.square(x[..., d, :])
+    return out
 
 
 class _RangeMin:
@@ -347,11 +364,17 @@ class _Engine:
         cap_rows = batch.cap[bsel_l, tau_l]      # (A, Dp), padded dims 1
         start_pad = batch.start.astype(np.int64)
         end_pad = batch.end.astype(np.int64)
-        # pool_n caches pool / capx so similarity steps skip the big
-        # division pass; the window of each updated row is re-divided
+        # pool_n caches pool / capx time-innermost, (A, Wcap, D, T'), and
+        # q its per-slot sum of squares over D, (A, Wcap, T'), so the
+        # similarity sums run over contiguous time and skip the big
+        # division pass.  The window of each updated row is re-divided
         # after the update, which is bitwise what find_fit computes from
-        # the current rem
-        pool_n = pool_l / capx[:, None, None, :] if similarity else None
+        # the current rem; padded dims divide by +inf to exact zeros
+        pool_n = q = None
+        if similarity:
+            pool_n = np.ascontiguousarray(
+                (pool_l / capx[:, None, None, :]).transpose(0, 1, 3, 2))
+            q = _sum_sq(pool_n)
 
         def write_back(rows):
             """Store finished live rows in the wave pool (grown if the
@@ -385,7 +408,7 @@ class _Engine:
                 tau_l, bsel_l = tau_l[live], bsel_l[live]
                 capx, cap_rows = capx[live], cap_rows[live]
                 if pool_n is not None:
-                    pool_n = pool_n[live]
+                    pool_n, q = pool_n[live], q[live]
                 A = len(live)
                 arows = np.arange(A)
                 done = np.zeros(A, bool)
@@ -396,6 +419,7 @@ class _Engine:
                 if pool_n is not None:
                     pool_n = np.concatenate(
                         [pool_n, np.zeros_like(pool_n)], axis=1)
+                    q = np.concatenate([q, np.zeros_like(q)], axis=1)
 
             alive = ~done
             u_cur = u_pad[arows, np.minimum(ptr, lens - 1)]
@@ -427,14 +451,17 @@ class _Engine:
                     & span_flat[:, None, :]).any(axis=2)
             feas = ~viol & node_ok
             if similarity:
-                # the window's dropped slots carry only exact-zero
-                # terms, so the einsum accumulations are unchanged
-                rem_n = pool_n[:, :W, t0:t1]
-                dem_n = dem / capx
+                # two-operand einsums over contiguous time, then over D:
+                # every row is reduced in the same order, so nodes equal
+                # over the span score bit-identically (a batched BLAS
+                # gemv orders each row's sum by its position, and can
+                # split such ties).  The window's dropped slots carry
+                # only exact-zero terms, so the sums are unchanged
                 span_f = span.astype(np.float64)
-                dot = np.einsum("bntd,bd,bt->bn", rem_n, dem_n, span_f)
-                norm2 = np.einsum("bntd,bntd,bt->bn", rem_n, rem_n,
-                                  span_f)
+                per_d = np.einsum("bndt,bt->bnd",
+                                  pool_n[:, :W, :, t0:t1], span_f)
+                dot = np.einsum("bnd,bd->bn", per_d, dem / capx)
+                norm2 = np.einsum("bnt,bt->bn", q[:, :W, t0:t1], span_f)
                 dem_norm = self.dn[bsel_l, u_cur]
                 score = dot / (dem_norm[:, None] * np.sqrt(norm2)
                                + 1e-30)
@@ -458,13 +485,14 @@ class _Engine:
                         f"mapping assigned task {int(u_cur[a0])} "
                         f"to node-type {int(tau_l[a0])} it cannot fit")
                 j_new = wl[buy_a]
-                # a new node is full over the whole timeline, and so is
-                # its cached pool / capx row; the update below then
-                # touches only the window of either
+                # a new node is full over the whole timeline, and so are
+                # its cached pool / capx and q rows; the update below
+                # then touches only the window of each
                 pool_l[buy_a, j_new] = cap_rows[buy_a][:, None]
                 if pool_n is not None:
-                    pool_n[buy_a, j_new] = (
-                        cap_rows[buy_a] / capx[buy_a])[:, None]
+                    full_n = (cap_rows[buy_a] / capx[buy_a])[:, :, None]
+                    pool_n[buy_a, j_new] = full_n
+                    q[buy_a, j_new] = _sum_sq(full_n)
                 wl[buy_a] += 1
                 self.counts[bsel_l[buy_a]] += 1
                 place_a = np.concatenate([place_a, buy_a])
@@ -476,9 +504,10 @@ class _Engine:
                 # task's span is empty, so those slots stay as they were
                 pool_l[place_a, j_all, t0:t1] -= sub
                 if pool_n is not None:
-                    pool_n[place_a, j_all, t0:t1] = (
-                        pool_l[place_a, j_all, t0:t1]
-                        / capx[place_a][:, None, :])
+                    win_n = (pool_l[place_a, j_all, t0:t1]
+                             / capx[place_a][:, None, :]).transpose(0, 2, 1)
+                    pool_n[place_a, j_all, :, t0:t1] = win_n
+                    q[place_a, j_all, t0:t1] = _sum_sq(win_n)
                 u_sel = u_cur[place_a]
                 b_sel = bsel_l[place_a]
                 # global node id = block start + pool-local index

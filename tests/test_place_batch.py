@@ -356,6 +356,87 @@ class TestCrossFillSkip:
         assert tel["fill_attempts"] == tel["fill_skipped"] == 0
 
 
+def _tie_grid(B=16, T=31):
+    """B instances on an odd 31-slot timeline in which a probe task
+    meets two classes of tied type-0 nodes.  Nine or more openers
+    (demand > 0.5, spans early and of random length) each buy a node;
+    the mid tasks, equal to one another, then load nodes 0 .. n_mid - 1
+    alike, and the probe's span starts inside theirs.  Over the probe's
+    span the mid nodes hold bit-identical remaining capacity, and so do
+    the untouched nodes n_mid, ...; outside it every node differs by
+    its opener.  Skewed demands make either class win in some
+    instances.  Type-1 tasks, early, cross-fill afterwards.  Returns
+    the problems, mappings, probe ids and mid-task counts."""
+    from repro.core import NodeTypes, Problem
+
+    nt = NodeTypes(cap=np.array([[1.0, 1.0], [2.0, 2.0]]),
+                   cost=np.array([1.0, 2.5]))
+    problems, maps, probes, n_mids = [], [], [], []
+    for b in range(B):
+        rng = np.random.default_rng(400 + b)
+        n_open = int(rng.integers(9, 15))
+        n_mid = int(rng.integers(2, n_open - 2))
+        s_mid = int(rng.integers(8, 12))
+        e_mid = s_mid + int(rng.integers(3, 8))
+        ps = int(rng.integers(s_mid + 1, e_mid))
+        pe = int(rng.integers(e_mid + 1, T - 1))
+        n_fill = 12
+        fill_s = rng.integers(0, s_mid - 2, n_fill)
+        start = np.concatenate([np.zeros(n_open, np.int64),
+                                np.full(n_mid, s_mid), [ps], fill_s])
+        end = np.concatenate([
+            rng.integers(0, s_mid - 1, n_open), np.full(n_mid, e_mid),
+            [pe], np.minimum(fill_s + rng.integers(0, 4, n_fill),
+                             s_mid - 2)])
+        mid = rng.permutation([rng.uniform(0.55, 0.75),
+                               rng.uniform(0.0, 0.2)])
+        probe = rng.permutation([rng.uniform(0.01, 0.05),
+                                 rng.uniform(0.1, 0.25)])
+        dem = np.concatenate([rng.uniform(0.51, 0.95, (n_open, 2)),
+                              np.tile(mid, (n_mid, 1)), probe[None],
+                              rng.uniform(0.05, 0.4, (n_fill, 2))])
+        mp = np.zeros(len(start), np.int64)
+        mp[-n_fill:] = 1
+        problems.append(Problem(dem=dem, start=start, end=end,
+                                node_types=nt, T=T))
+        maps.append(mp)
+        probes.append(n_open + n_mid)
+        n_mids.append(n_mid)
+    return problems, maps, probes, n_mids
+
+
+class TestSimilarityScoring:
+    def test_tied_nodes_pick_the_lowest_index_like_the_loop(self):
+        problems, maps, probes, n_mids = _tie_grid()
+        batch = pack_problems(problems, assume_trimmed=True)
+        assert batch.Tp % 2 == 1
+        sols = place_many(batch, maps, fit="similarity", filling=True)
+        picked = []
+        for t, mp, got, p, n_mid in zip(batch.problems, maps, sols,
+                                        probes, n_mids):
+            want = two_phase(t, mp, fit="similarity", filling=True)
+            _assert_equal_solutions(got, want)
+            assert got.cost(t) == want.cost(t)
+            # the lowest index of the mid class or of the untouched one
+            assert got.assign[p] in (0, n_mid)
+            picked.append(got.assign[p] == 0)
+        assert 0 < sum(picked) < len(picked)  # both classes win
+
+    def test_robust_scenarios_place_like_the_loop(self):
+        from repro.stochastic import fan_out, gct_forecast
+
+        fc = gct_forecast(n=200, m=6, seed=3)
+        assert fc.burst_prob > 0
+        batch = pack_problems(fan_out(fc, K=16, seed=7).problems)
+        maps = [r.mapping for r in solve_lp_many(batch, iters=2000,
+                                                 tol=5e-3)]
+        sols = place_many(batch, maps, fit="similarity", filling=True)
+        for t, mp, got in zip(batch.problems, maps, sols):
+            want = two_phase(t, mp, fit="similarity", filling=True)
+            _assert_equal_solutions(got, want)
+            assert got.cost(t) == want.cost(t)
+
+
 class TestRangeMin:
     @pytest.mark.parametrize("T", [1, 2, 7, 32, 45])
     def test_queries_match_brute_force_after_updates(self, T):
